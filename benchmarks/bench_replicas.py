@@ -17,7 +17,9 @@ Three measurements (paper §3.2, flexible resource allocation):
   D. process isolation overhead — the same slowed-stage workload served
      by 2 ``isolation="process"`` replicas: spawned workers, items over
      named shared-memory segments.  Compares against B's threaded
-     2-replica rate to price the cross-process hop.
+     2-replica rate to price the cross-process hop.  The stage is a
+     host-only stub: a process replica that needs JAX is refused once
+     the parent holds an accelerator.
 
   PYTHONPATH=src python -m benchmarks.bench_replicas [--smoke]
       [--json OUT.json]
@@ -101,7 +103,8 @@ def _scaling(n_requests: int, dwell_s: float, seed: int) -> Dict[str, float]:
 
 def _process_scaling(n_requests: int, dwell_s: float, seed: int) -> float:
     """D: the 2-replica scaling run again, but each replica is a spawned
-    process worker fed through shared-memory segments."""
+    process worker fed through shared-memory segments (host-only stub
+    engines, so the row runs on any parent)."""
     graph = StageGraph()
     graph.add_stage(StageSpec("slow", "custom", is_output=True))
     spec = EngineSpec("repro.engine.stub_engine:make_stub",
@@ -241,7 +244,7 @@ def run(n_requests: int = 24, dwell_ms: float = 20.0, families: int = 4,
     proc = _process_scaling(n_requests, dwell_ms / 1e3, seed)
     ratio = proc / thr[2] if thr[2] else 0.0
     rows.append(("replicas_2x_process_finished_per_s", proc * 1e3,
-                 f"{proc:.1f} req/s isolation=process "
+                 f"{proc:.1f} req/s isolation=process host-only "
                  f"({100*ratio:.0f}% of threaded 2x)"))
 
     base = _affinity_hit_rate(1, "affinity", families=families,

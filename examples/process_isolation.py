@@ -7,14 +7,22 @@ connector (named segments + manifests), and greedy outputs match the
 all-thread run exactly.  Killing a process replica mid-run re-admits
 its in-flight requests to the survivor — zero requests lost.
 
+Host-only: both stages are JAX engines, and a spawned child cannot reach
+an accelerator the parent already holds (the Orchestrator refuses to
+spawn one there), so the example pins JAX to the CPU in parent and child.
+
   PYTHONPATH=src python examples/process_isolation.py
 """
-import numpy as np
+import os
 
-from repro.configs.pipelines import build_pd_disaggregated
-from repro.core.config import ServeConfig, StageConfig
-from repro.core.orchestrator import Orchestrator
-from repro.core.request import Request
+os.environ["JAX_PLATFORMS"] = "cpu"     # before jax is imported; inherited
+
+import numpy as np  # noqa: E402
+
+from repro.configs.pipelines import build_pd_disaggregated  # noqa: E402
+from repro.core.config import ServeConfig, StageConfig  # noqa: E402
+from repro.core.orchestrator import Orchestrator  # noqa: E402
+from repro.core.request import Request  # noqa: E402
 
 
 def main():

@@ -42,7 +42,8 @@ class MooncakeConnector(Connector):
                 arr = np.asarray(leaf)
                 raw = arr.tobytes()
                 nbytes += len(raw)
-                blobs.append(("arr", raw, arr.dtype.str, arr.shape))
+                # the dtype object, not .str: bfloat16's is void "<V2"
+                blobs.append(("arr", raw, arr.dtype, arr.shape))
             else:
                 blobs.append(("py", leaf, None, None))
         return (blobs, treedef, nbytes), self._wire_time(nbytes)

@@ -68,7 +68,8 @@ class SharedMemoryConnector(Connector):
                 arr = np.asarray(leaf)
                 raw = arr.tobytes()
                 nbytes += len(raw)
-                bufs.append(("arr", raw, arr.dtype.str, arr.shape))
+                # the dtype object, not .str: bfloat16's is void "<V2"
+                bufs.append(("arr", raw, arr.dtype, arr.shape))
             else:
                 bufs.append(("py", leaf, None, None))
         return (bufs, treedef, nbytes), 0.0

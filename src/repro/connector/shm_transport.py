@@ -47,13 +47,14 @@ class SegmentManifest:
     """Everything a *different process* needs to rebuild the payload.
 
     Picklable; ship it over any control channel.  ``slots`` are
-    ``(dtype_str, shape, offset, size)`` views into the named segment;
+    ``(dtype, shape, offset, size)`` views into the named segment;
     ``skeleton`` is the payload structure with arrays replaced by
     :class:`_ArrRef` markers and all other leaves inline.
     """
     segment: Optional[str]               # None: no arrays, skeleton-only
     nbytes: int
-    slots: List[Tuple[str, tuple, int, int]] = field(default_factory=list)
+    slots: List[Tuple[np.dtype, tuple, int, int]] = field(
+        default_factory=list)
     skeleton: Any = None
 
 
@@ -92,10 +93,11 @@ def write_segment(payload: Any) -> Tuple[Optional[Any], SegmentManifest]:
         raise RuntimeError("shared_memory unavailable on this platform")
     arrays: List[np.ndarray] = []
     skeleton = _flatten(payload, arrays)
-    slots: List[Tuple[str, tuple, int, int]] = []
+    # dtype objects, not .str: bfloat16's is the void "<V2"
+    slots: List[Tuple[np.dtype, tuple, int, int]] = []
     offset = 0
     for a in arrays:
-        slots.append((a.dtype.str, tuple(a.shape), offset, a.nbytes))
+        slots.append((a.dtype, tuple(a.shape), offset, a.nbytes))
         offset += a.nbytes
     if not arrays or offset == 0:
         # no array bytes to share — but keep slot metadata so zero-size

@@ -49,6 +49,7 @@ import time
 import warnings
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.connector import shm_transport
@@ -135,6 +136,23 @@ def make_routing_policy(name: str) -> RoutingPolicy:
     return ROUTING_POLICIES[name]()
 
 
+def _refuse_device_child(stage: str, engines: List[Any]) -> None:
+    """A spawned replica rebuilds its engine in a fresh process.  Once this
+    process holds an accelerator, a child that needs JAX cannot get the
+    device and JAX would quietly serve it from the CPU — refuse instead.
+    Engines that declare ``host_only = True`` never touch JAX."""
+    if all(getattr(e, "host_only", False) for e in engines):
+        return
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise ValueError(
+            f"stage {stage!r}: isolation='process' would spawn a child that "
+            f"rebuilds a JAX engine, but this process already holds the "
+            f"{backend} device and the child would run on the CPU; serve "
+            f"the stage with isolation='thread' (only host_only engines "
+            f"may be process-isolated here)")
+
+
 _LEGACY_KWARGS = ("backend", "queue_capacity", "recv_timeout", "replicas",
                   "routing", "engine_factories", "engine_specs",
                   "isolation", "warm_seed")
@@ -194,6 +212,7 @@ class Orchestrator:
         for name in graph.stages:
             sc = config.stage(name)
             if sc.isolation == "process":
+                _refuse_device_child(name, self.stage_replicas[name])
                 self._proc_replicas[name] = max(
                     sc.replicas, len(self.stage_replicas[name]))
                 continue

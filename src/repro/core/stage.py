@@ -41,7 +41,10 @@ class StageEngine(Protocol):
         produced: finished outputs, streamed chunks;
       - ``has_work`` is cheap and may be read from other threads for
         quiescence detection (it is advisory there — the worker's own
-        thread re-checks before sleeping).
+        thread re-checks before sleeping);
+      - an engine that never touches JAX may set ``host_only = True``;
+        only such stages can be process-isolated once this process
+        holds an accelerator (a spawned child could not reach it).
     """
 
     name: str

@@ -90,9 +90,15 @@ class DiffusionEngine:
             return events
         t0 = time.perf_counter()
         self.steps += 1
-        # bucket by (cond_len, out_len); batch the largest bucket
+        # bucket by (cond_len, out_len); batch the largest bucket.  Only
+        # each request's oldest queued chunk is eligible: a stream's chunks
+        # must finish in order, since its last chunk completes the request
         buckets: Dict[tuple, List[_DiffJob]] = {}
+        heads = set()
         for job in self.queue:
+            if job.req_id in heads:
+                continue
+            heads.add(job.req_id)
             buckets.setdefault((job.cond.shape[0], job.out_len),
                                []).append(job)
         key_, jobs = max(buckets.items(), key=lambda kv: len(kv[1]))

@@ -1,6 +1,7 @@
 """Paged KV cache manager (vLLM-style) + SSM state cache.
 
-The page pool is a pair of arrays (L, P, page, nkv, hd); sequences own
+The page pool is a pair of head-major arrays (L, P, nkv, page, hd), so
+each head's page is one (page, hd) tile for the decode kernel; sequences own
 pages through int32 block tables. Allocation is a host-side free list; the
 device arrays are only touched inside the jitted step functions.
 
@@ -351,14 +352,14 @@ class PagedKVConfig:
 def init_kv_pages(cfg: ModelConfig, kv: PagedKVConfig, num_layers: int):
     dtype = (jnp.int8 if cfg.kv_cache_dtype == "int8"
              else jnp.dtype(cfg.dtype))
-    shape = (num_layers, kv.num_pages, kv.page_size, cfg.num_kv_heads,
+    shape = (num_layers, kv.num_pages, cfg.num_kv_heads, kv.page_size,
              cfg.head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
 def init_kv_scale_pages(cfg: ModelConfig, kv: PagedKVConfig,
                         num_layers: int):
-    shape = (num_layers, kv.num_pages, kv.page_size, cfg.num_kv_heads)
+    shape = (num_layers, kv.num_pages, cfg.num_kv_heads, kv.page_size)
     return jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
 
 

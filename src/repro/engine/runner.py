@@ -92,23 +92,19 @@ class PagedRunner:
             if self.quant:
                 kq, ks = L.quantize_kv(k)
                 vq, vs = L.quantize_kv(v)
-                kp = kp.at[pid, slot].set(kq[0], mode="drop")
-                vp = vp.at[pid, slot].set(vq[0], mode="drop")
-                ksp = ksp.at[pid, slot].set(ks[0], mode="drop")
-                vsp = vsp.at[pid, slot].set(vs[0], mode="drop")
-                k_all = (kp[block_table].astype(jnp.float32)
-                         * ksp[block_table].astype(jnp.float32)[..., None])
-                v_all = (vp[block_table].astype(jnp.float32)
-                         * vsp[block_table].astype(jnp.float32)[..., None])
-                k_all = k_all.astype(h.dtype)
-                v_all = v_all.astype(h.dtype)
+                kp = kp.at[pid, :, slot].set(kq[0], mode="drop")
+                vp = vp.at[pid, :, slot].set(vq[0], mode="drop")
+                ksp = ksp.at[pid, :, slot].set(ks[0], mode="drop")
+                vsp = vsp.at[pid, :, slot].set(vs[0], mode="drop")
             else:
-                kp = kp.at[pid, slot].set(k[0], mode="drop")
-                vp = vp.at[pid, slot].set(v[0], mode="drop")
-                k_all, v_all = kp[block_table], vp[block_table]
-            k_all = k_all.reshape(1, -1, cfg.num_kv_heads, cfg.head_dim)
-            v_all = v_all.reshape(1, -1, cfg.num_kv_heads, cfg.head_dim)
-            o = ref.chunk_attention(q, k_all, v_all, start, window=window)
+                kp = kp.at[pid, :, slot].set(k[0].astype(kp.dtype),
+                                             mode="drop")
+                vp = vp.at[pid, :, slot].set(v[0].astype(vp.dtype),
+                                             mode="drop")
+            k_all = ref.gather_pages(kp, block_table, ksp).astype(h.dtype)
+            v_all = ref.gather_pages(vp, block_table, vsp).astype(h.dtype)
+            o = ref.chunk_attention(q, k_all[None], v_all[None], start,
+                                    window=window)
             h = h + jnp.einsum("bsqh,qhd->bsd", o, lp["attn"]["wo"])
             hn = L.rmsnorm(lp["ln2"], h, cfg.rmsnorm_eps)
             h = h + _mlp_or_moe(cfg, lp, hn)
@@ -154,22 +150,25 @@ class PagedRunner:
     def extract_kv(self, block_table, n_tokens: int):
         """Pull one request's prompt KV out of the page pool.
 
-        Returns (k, v): (L, n_pages*page, nkv, hd) host arrays (trailing
-        padding past n_tokens is zeros) — the payload a prefill stage ships
-        to a decode stage through the unified connector.
+        Returns (k, v): (L, n_pages*page, nkv, hd) token-major host arrays
+        (trailing padding past n_tokens is zeros) — the payload a prefill
+        stage ships to a decode stage through the unified connector.
         """
         page = self.kv.page_size
         n_pages = -(-n_tokens // page)
         bt = jnp.asarray(block_table[:n_pages])
-        k = self.k_pages[:, bt]
-        v = self.v_pages[:, bt]
-        if self.quant:
-            # ship full-precision KV (the receiving stage re-quantizes)
-            k = k.astype(jnp.float32) * self.k_scales[:, bt][..., None]
-            v = v.astype(jnp.float32) * self.v_scales[:, bt][..., None]
         shape = (self.cfg.num_layers, n_pages * page,
                  self.cfg.num_kv_heads, self.cfg.head_dim)
-        return np.asarray(k.reshape(shape)), np.asarray(v.reshape(shape))
+
+        def tokens(pages, scales):
+            x = pages[:, bt]                        # (L, n, nkv, page, hd)
+            if self.quant:
+                # ship full-precision KV (the receiving stage re-quantizes)
+                x = x.astype(jnp.float32) * scales[:, bt][..., None]
+            return np.asarray(jnp.swapaxes(x, 2, 3).reshape(shape))
+
+        return (tokens(self.k_pages, self.k_scales),
+                tokens(self.v_pages, self.v_scales))
 
     def inject_kv(self, k_seed, v_seed, block_table, n_tokens: int) -> None:
         """Write transferred prompt KV into this engine's page pool."""
@@ -181,8 +180,10 @@ class PagedRunner:
             k_seed = np.pad(k_seed, padw)
             v_seed = np.pad(v_seed, padw)
         Ln, _, nkv, hd = k_seed.shape
-        kp = jnp.asarray(k_seed.reshape(Ln, n_pages, page, nkv, hd))
-        vp = jnp.asarray(v_seed.reshape(Ln, n_pages, page, nkv, hd))
+        kp = jnp.asarray(k_seed.reshape(Ln, n_pages, page, nkv, hd)
+                         .swapaxes(2, 3))
+        vp = jnp.asarray(v_seed.reshape(Ln, n_pages, page, nkv, hd)
+                         .swapaxes(2, 3))
         bt = jnp.asarray(block_table[:n_pages])
         if self.quant:
             from repro.models.layers import quantize_kv
@@ -223,13 +224,15 @@ class PagedRunner:
             if self.quant:
                 kq, ks = L.quantize_kv(k)
                 vq, vs = L.quantize_kv(v)
-                kp = kp.at[pid, slot].set(kq[:, 0], mode="drop")
-                vp = vp.at[pid, slot].set(vq[:, 0], mode="drop")
-                ksp = ksp.at[pid, slot].set(ks[:, 0], mode="drop")
-                vsp = vsp.at[pid, slot].set(vs[:, 0], mode="drop")
+                kp = kp.at[pid, :, slot].set(kq[:, 0], mode="drop")
+                vp = vp.at[pid, :, slot].set(vq[:, 0], mode="drop")
+                ksp = ksp.at[pid, :, slot].set(ks[:, 0], mode="drop")
+                vsp = vsp.at[pid, :, slot].set(vs[:, 0], mode="drop")
             else:
-                kp = kp.at[pid, slot].set(k[:, 0], mode="drop")
-                vp = vp.at[pid, slot].set(v[:, 0], mode="drop")
+                kp = kp.at[pid, :, slot].set(k[:, 0].astype(kp.dtype),
+                                             mode="drop")
+                vp = vp.at[pid, :, slot].set(v[:, 0].astype(vp.dtype),
+                                             mode="drop")
             o = ops.paged_attention(q[:, 0], kp, vp, block_tables, seq_lens,
                                     window=window, k_scale_pages=ksp,
                                     v_scale_pages=vsp)
@@ -290,7 +293,7 @@ class StateRunner:
 
     def _insert_impl(self, cache, cache1, slot):
         def ins(c, c1):
-            return c.at[:, slot].set(c1[:, 0])
+            return c.at[:, slot].set(c1[:, 0].astype(c.dtype))
         return jax.tree.map(ins, cache, cache1)
 
     def prefill(self, embeds, slot):

@@ -23,6 +23,8 @@ class StubEngine:
     ``dwell_s`` (a sleep, so replicas overlap like independent devices)
     and emits its inputs back as the finished payload."""
 
+    host_only = True    # never touches JAX: safe to process-isolate
+
     def __init__(self, name: str, dwell_s: float = 0.0):
         self.name = name
         self.dwell_s = dwell_s

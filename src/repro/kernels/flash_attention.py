@@ -11,6 +11,8 @@ TPU-native design notes (vs the CUDA flash-attention the paper's engines use):
     the warp-level reduction in the GPU kernel).
   - GQA: the kernel indexes K/V by q_head // group via the BlockSpec
     index_map, so K/V tiles are fetched once per kv-head group.
+  - Lengths that are not a multiple of the block are padded up to one;
+    padded keys are masked and padded query rows are dropped.
 
 Validated against kernels/ref.py with interpret=True in tests/test_kernels.py.
 """
@@ -45,7 +47,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     # positions for masking (query positions aligned to the end of keys)
     qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + (sk - sq)
     kpos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = jnp.ones((bq, bk), jnp.bool_)
+    mask = kpos < sk                                     # padded keys
     if causal:
         mask &= kpos <= qpos
     if window > 0:
@@ -78,16 +80,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     b, sq, nq, hd = q.shape
     _, sk, nkv, _ = k.shape
     g = nq // nkv
-    bq = min(bq, sq)
-    bk = min(bk, sk)
-    assert sq % bq == 0 and sk % bk == 0, (sq, bq, sk, bk)
+    bq = min(bq, _round_up(sq, 8))
+    bk = min(bk, _round_up(sk, 8))
+    sqp, skp = _round_up(sq, bq), _round_up(sk, bk)
     scale = hd ** -0.5
 
-    qt = q.transpose(0, 2, 1, 3)  # (B, nq, Sq, hd)
-    kt = k.transpose(0, 2, 1, 3)  # (B, nkv, Sk, hd)
-    vt = v.transpose(0, 2, 1, 3)
+    def heads_major(x, sp):  # (B, S, h, hd) -> (B, h, Sp, hd)
+        return jnp.pad(x, ((0, 0), (0, sp - x.shape[1]), (0, 0), (0, 0))
+                       ).transpose(0, 2, 1, 3)
 
-    grid = (b, nq, sq // bq, sk // bk)
+    qt, kt, vt = heads_major(q, sqp), heads_major(k, skp), heads_major(v, skp)
+
+    grid = (b, nq, sqp // bq, skp // bk)
     out = pl.pallas_call(
         functools.partial(_fa_kernel, scale=scale, causal=causal,
                           window=window, bq=bq, bk=bk, sk=sk, sq=sq),
@@ -98,7 +102,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pl.BlockSpec((1, 1, bk, hd), lambda b_, h, i, j: (b_, h // g, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b_, h, i, j: (b_, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, nq, sq, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, nq, sqp, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),   # running max m
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum l
@@ -106,4 +110,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         interpret=interpret,
     )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)
+    return out[:, :, :sq].transpose(0, 2, 1, 3)
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m

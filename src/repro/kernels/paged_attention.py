@@ -11,12 +11,17 @@ TPU-native design notes:
     BlockSpec index_map dereferences the page id *before* the DMA is
     issued — the TPU equivalent of the GPU kernel's pointer chasing, with
     the DMA engine doing the gather.
-  - Pages are (page_size, head_dim) tiles; page_size is a multiple of 8
-    (sublane) and head_dim a multiple of 128 lanes for aligned VMEM tiles.
-  - GQA: all g query heads of one kv head are processed together as the
-    rows of a (g, hd) MXU tile.
+  - The pool is head-major, (P, nkv, page, hd): one grid step DMAs a whole
+    page for every kv head, and each head's (page, hd) slab is a full
+    (sublane, lane) tile, so no block puts a 1 in the sublane dim.
+  - GQA: the g query heads of one kv head are the rows of a (g, hd) MXU
+    tile; kv heads are a static loop inside the step.
+  - int8 pools keep HBM traffic at 1 B/elem: the per-token scales are
+    applied to the (g, page) scores and probabilities, where they broadcast
+    along sublanes, instead of to the K/V tiles.
 
-Validated against kernels/ref.py (interpret=True) in tests/test_kernels.py.
+Validated against kernels/ref.py (interpret=True) in tests/test_kernels.py
+and compiled for v5e in tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -31,11 +36,15 @@ _NEG_INF = -2.0 ** 30
 
 
 def _pa_kernel(block_tables_ref, seq_lens_ref,  # scalar prefetch
-               q_ref, k_ref, v_ref, o_ref,
-               m_ref, l_ref, acc_ref, *,
-               page: int, window: int, ks_ref=None, vs_ref=None):
+               q_ref, k_ref, v_ref, *refs, page: int, window: int,
+               quant: bool):
+    if quant:
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        o_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
-    pi = pl.program_id(2)
+    pi = pl.program_id(1)
+    nkv, g = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(pi == 0)
     def _init():
@@ -43,42 +52,34 @@ def _pa_kernel(block_tables_ref, seq_lens_ref,  # scalar prefetch
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                  # (g, hd) — pre-scaled
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (page, hd)
-    if ks_ref is not None:
-        # int8 page pool: dequantize in-VMEM (HBM traffic stays 1 B/elem)
-        k = k * ks_ref[0, :, 0][:, None].astype(jnp.float32)
-    s = q @ k.T                                          # (g, page)
-
     seq_len = seq_lens_ref[b]
-    tok = pi * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    tok = pi * page + jax.lax.broadcasted_iota(jnp.int32, (g, page), 1)
     mask = tok < seq_len
     if window > 0:
         mask &= tok > seq_len - 1 - window
-    s = jnp.where(mask, s, _NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    if vs_ref is not None:
-        v = v * vs_ref[0, :, 0][:, None].astype(jnp.float32)
-    acc_ref[...] = acc_ref[...] * alpha + p @ v
-    m_ref[...] = m_new
+    for h in range(nkv):
+        q = q_ref[0, h]                                  # (g, hd) f32, scaled
+        k = k_ref[0, h].astype(jnp.float32)              # (page, hd)
+        s = q @ k.T                                      # (g, page)
+        if quant:
+            s = s * ks_ref[0, h:h + 1, :]                # (1, page) scales
+        s = jnp.where(mask, s, _NEG_INF)
+        m_prev = m_ref[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
+        if quant:
+            p = p * vs_ref[0, h:h + 1, :]
+        v = v_ref[0, h].astype(jnp.float32)
+        acc_ref[h] = acc_ref[h] * alpha + p @ v
+        m_ref[h] = m_new
 
-    @pl.when(pi == pl.num_programs(2) - 1)
+    @pl.when(pi == pl.num_programs(1) - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0, ...] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def _pa_kernel_quant(bt_ref, sl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                     o_ref, m_ref, l_ref, acc_ref, *, page, window):
-    _pa_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-               acc_ref, page=page, window=window, ks_ref=ks_ref,
-               vs_ref=vs_ref)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -87,57 +88,51 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     k_scale_pages: jax.Array | None = None,
                     v_scale_pages: jax.Array | None = None,
                     window: int = 0, interpret: bool = False) -> jax.Array:
-    """q: (B, nq, hd); k/v_pages: (P, page, nkv, hd);
+    """q: (B, nq, hd); k/v_pages: (P, nkv, page, hd);
     block_tables: (B, pages_per_seq) int32; seq_lens: (B,) int32.
-    Optional k/v_scale_pages: (P, page, nkv) f32 — int8-quantized pool with
+    Optional k/v_scale_pages: (P, nkv, page) f32 — int8-quantized pool with
     in-kernel dequantization. Returns (B, nq, hd)."""
     b, nq, hd = q.shape
-    num_pages, page, nkv, _ = k_pages.shape
+    _, nkv, page, _ = k_pages.shape
     pp = block_tables.shape[1]
     g = nq // nkv
     scale = hd ** -0.5
     quant = k_scale_pages is not None
 
-    # (B, nkv, g, hd) so each kv head's query group is one tile
-    qg = (q * scale).reshape(b, nkv, g, hd)
+    # (B, nkv, g, hd) so each kv head's query group is one tile; scaled in
+    # f32, as the reference does (a bf16 product would round q)
+    qg = (q.astype(jnp.float32) * scale).reshape(b, nkv, g, hd)
+
+    def page_block(*tail):
+        # dereference the page id from the prefetched block table
+        return pl.BlockSpec((1, nkv) + tail,
+                            lambda b_, p, bt, sl: (bt[b_, p], 0)
+                            + (0,) * len(tail))
 
     in_specs = [
-        pl.BlockSpec((1, 1, g, hd),
-                     lambda b_, h, p, bt, sl: (b_, h, 0, 0)),
-        # dereference the page id from the prefetched block table
-        pl.BlockSpec((1, page, 1, hd),
-                     lambda b_, h, p, bt, sl: (bt[b_, p], 0, h, 0)),
-        pl.BlockSpec((1, page, 1, hd),
-                     lambda b_, h, p, bt, sl: (bt[b_, p], 0, h, 0)),
+        pl.BlockSpec((1, nkv, g, hd), lambda b_, p, bt, sl: (b_, 0, 0, 0)),
+        page_block(page, hd),
+        page_block(page, hd),
     ]
     operands = [block_tables, seq_lens, qg, k_pages, v_pages]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, page, 1),
-                         lambda b_, h, p, bt, sl: (bt[b_, p], 0, h)),
-            pl.BlockSpec((1, page, 1),
-                         lambda b_, h, p, bt, sl: (bt[b_, p], 0, h)),
-        ]
+        in_specs += [page_block(page), page_block(page)]
         operands += [k_scale_pages, v_scale_pages]
-        kernel = functools.partial(_pa_kernel_quant, page=page,
-                                   window=window)
-    else:
-        kernel = functools.partial(_pa_kernel, page=page, window=window)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, nkv, pp),
+        grid=(b, pp),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda b_, h, p, bt, sl: (b_, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, nkv, g, hd),
+                               lambda b_, p, bt, sl: (b_, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
+            pltpu.VMEM((nkv, g, 1), jnp.float32),
+            pltpu.VMEM((nkv, g, 1), jnp.float32),
+            pltpu.VMEM((nkv, g, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_pa_kernel, page=page, window=window, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, g, hd), q.dtype),
         interpret=interpret,

@@ -101,34 +101,43 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Decode attention over a block-paged KV cache (vLLM PagedAttention).
 
     q: (B, nq, hd) — one query token per sequence.
-    k_pages/v_pages: (num_pages, page_size, nkv, hd) — the global page pool.
+    k_pages/v_pages: (num_pages, nkv, page_size, hd) — the global page pool,
+    head-major so each head's page is one (page, hd) tile.
     block_tables: (B, pages_per_seq) int32 page ids (padded arbitrarily).
     seq_lens: (B,) int32 — number of valid tokens (incl. current).
-    k/v_scale_pages: optional (num_pages, page_size, nkv) dequant scales for
+    k/v_scale_pages: optional (num_pages, nkv, page_size) dequant scales for
     int8-quantized page pools.
     """
     b, nq, hd = q.shape
-    num_pages, page, nkv, _ = k_pages.shape
+    k = gather_pages(k_pages, block_tables, k_scale_pages)  # (B, T, nkv, hd)
+    v = gather_pages(v_pages, block_tables, v_scale_pages)
+    nkv, t = k.shape[2], k.shape[1]
     scale = scale if scale is not None else hd ** -0.5
-    k = k_pages[block_tables].astype(jnp.float32)  # (B, pp, page, nkv, hd)
-    v = v_pages[block_tables].astype(jnp.float32)
-    if k_scale_pages is not None:
-        k = k * k_scale_pages[block_tables].astype(jnp.float32)[..., None]
-    if v_scale_pages is not None:
-        v = v * v_scale_pages[block_tables].astype(jnp.float32)[..., None]
-    pp = block_tables.shape[1]
-    k = k.reshape(b, pp * page, nkv, hd)
-    v = v.reshape(b, pp * page, nkv, hd)
     qg = q.reshape(b, 1, nkv, nq // nkv, hd).astype(jnp.float32)
-    scores = jnp.einsum("bskgh,btkh->bkgst", qg * scale, k.astype(jnp.float32))
-    j = jnp.arange(pp * page)[None, :]
+    scores = jnp.einsum("bskgh,btkh->bkgst", qg * scale, k)
+    j = jnp.arange(t)[None, :]
     mask = j < seq_lens[:, None]
     if window > 0:
         mask &= j > (seq_lens[:, None] - 1 - window)
     scores = jnp.where(mask[:, None, None, None, :], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgst,btkh->bskgh", probs, v.astype(jnp.float32))
+    out = jnp.einsum("bkgst,btkh->bskgh", probs, v)
     return out.reshape(b, nq, hd).astype(q.dtype)
+
+
+def gather_pages(pages: jax.Array, block_tables: jax.Array,
+                 scale_pages: jax.Array | None = None) -> jax.Array:
+    """Token-major f32 K or V of each sequence from a head-major page pool.
+
+    pages: (P, nkv, page, hd); block_tables: (..., pp); scale_pages:
+    optional (P, nkv, page) int8 dequant scales.
+    Returns (..., pp * page, nkv, hd) float32.
+    """
+    x = pages[block_tables].astype(jnp.float32)   # (..., pp, nkv, page, hd)
+    if scale_pages is not None:
+        x = x * scale_pages[block_tables].astype(jnp.float32)[..., None]
+    x = jnp.swapaxes(x, -3, -2)                   # (..., pp, page, nkv, hd)
+    return x.reshape(*x.shape[:-4], -1, *x.shape[-2:])
 
 
 def chunk_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,

@@ -10,14 +10,19 @@ Three modes:
     worker thread while the front-end keeps admitting
       PYTHONPATH=src python -m repro.launch.serve --pipeline qwen_omni \
           --online --requests 16 --rate 4.0 --max-inflight 8
-  - single: serve one assigned architecture (smoke-scale) as a 1-stage graph
+  - single: serve one assigned architecture as a 1-stage graph, at its
+    smoke scale or, with --full-config, at its published widths
       PYTHONPATH=src python -m repro.launch.serve --arch mixtral_8x7b \
           --requests 4
+
+The process exits non-zero when a stage worker dies or any request fails
+or goes unserved.
 """
 from __future__ import annotations
 
 import argparse
 import queue
+import sys
 import time
 
 import jax
@@ -34,12 +39,13 @@ from repro.core.request import Request
 from repro.core.stage import StageSpec
 from repro.engine.ar_engine import AREngine
 from repro.engine.sampling import SamplingParams
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
 
 
 def build_single_arch(arch: str, max_batch: int, max_new: int, seed: int = 0,
-                      prefix_cache: bool = False):
-    cfg = get_config(arch, smoke=True)
+                      prefix_cache: bool = False, full_config: bool = False):
+    cfg = get_config(arch, smoke=not full_config)
     params = T.init_params(cfg, jax.random.PRNGKey(seed))
 
     def make_engine():
@@ -147,7 +153,8 @@ examples:
 """
 
 
-def main() -> None:
+def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         epilog=_EPILOG,
         formatter_class=argparse.RawDescriptionHelpFormatter)
@@ -155,6 +162,9 @@ def main() -> None:
                     choices=[None, "qwen_omni", "qwen3_omni", "glm_image",
                              "mimo_audio", "pd"])
     ap.add_argument("--arch", default=None)
+    ap.add_argument("--full-config", action="store_true",
+                    help="--arch at its published widths instead of the "
+                         "smoke-scale config")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=16)
@@ -246,7 +256,7 @@ def main() -> None:
     elif args.arch:
         graph, engines, bundle = build_single_arch(
             args.arch, args.max_batch, args.max_new, args.seed,
-            prefix_cache=args.prefix_cache)
+            prefix_cache=args.prefix_cache, full_config=args.full_config)
     else:
         ap.error("pass --pipeline or --arch")
 
@@ -333,7 +343,23 @@ def main() -> None:
                   f"{ps.get('partial_hits', 0)} partial hits) "
                   f"computed={ps['computed_tokens']} tokens "
                   f"(hit-rate {rate:.1f}%)")
+    return serve_status(orch, reqs)
+
+
+def serve_status(orch: Orchestrator, reqs) -> int:
+    """Exit status of a serving run: 1 when a stage worker died or any
+    request failed or never completed, else 0."""
+    failed = [r for r in reqs if r.failed]
+    unserved = [r for r in reqs if r.completion_time is None]
+    if orch.worker_error or failed or unserved:
+        print(f"FAILED: {len(failed)} failed, {len(unserved)} unserved of "
+              f"{len(reqs)} requests; worker error: {orch.worker_error}",
+              file=sys.stderr)
+        for r in failed:
+            print(f"  req {r.req_id}: {r.failed}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.train import checkpoint
 from repro.train.data import TokenStream
@@ -21,6 +22,7 @@ from repro.train.step import make_train_step
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=200)

@@ -22,7 +22,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ModelConfig
 from repro.models import moe as moe_base
@@ -102,14 +101,14 @@ def moe_forward_ep(cfg: ModelConfig, p: dict, x: jax.Array):
     assert ctx is not None
     dp = S.batch_spec(ctx.mesh, x.shape[0])      # None if B doesn't divide
     fn = _local_moe(cfg, ctx.model_axis, dp)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn, mesh=ctx.mesh,
         in_specs=(P(dp, None, None), P(None, None),
                   P(ctx.model_axis, None, None),
                   P(ctx.model_axis, None, None),
                   P(ctx.model_axis, None, None)),
         out_specs=(P(dp, None, None), P()),
-        check_rep=False)
+        check_vma=False)
     return mapped(x, p["router"], p["wg"], p["wu"], p["wd"])
 
 

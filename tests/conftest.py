@@ -1,8 +1,6 @@
 """Tests run on the single real CPU device (the 512-device forcing is
 confined to repro.launch.dryrun, which tests never import)."""
 import os
-import sys
-import types
 
 # make sure nothing leaked the dry-run device forcing into the test env
 flags = os.environ.get("XLA_FLAGS", "")
@@ -12,55 +10,6 @@ if "host_platform_device_count" in flags:
 
 import jax
 import pytest
-
-# ---------------------------------------------------------------------------
-# hypothesis shim: offline environments don't have hypothesis installed, and
-# 5 test modules import it at collection time.  When it's missing we install
-# a stub into sys.modules whose @given replaces each property test with a
-# zero-argument skipper, so the rest of each module still collects and runs.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    def _given(*_args, **_kwargs):
-        def deco(fn):
-            def _skipper():
-                pytest.skip("hypothesis not installed (offline environment)")
-            _skipper.__name__ = fn.__name__
-            _skipper.__doc__ = fn.__doc__
-            return _skipper
-        return deco
-
-    def _settings(*_args, **_kwargs):
-        def deco(fn):
-            return fn
-        return deco
-
-    class _AnyStrategy:
-        """Stands in for strategy builders: any attribute access or call
-        (st.integers(1, 8), hnp.arrays(...)) yields another stub."""
-
-        def __getattr__(self, _name):
-            return self
-
-        def __call__(self, *_a, **_k):
-            return self
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.__getattr__ = lambda name: _AnyStrategy()
-    _extra = types.ModuleType("hypothesis.extra")
-    _hnp = types.ModuleType("hypothesis.extra.numpy")
-    _hnp.__getattr__ = lambda name: _AnyStrategy()
-    _hyp.strategies = _st
-    _hyp.extra = _extra
-    _extra.numpy = _hnp
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
-    sys.modules["hypothesis.extra"] = _extra
-    sys.modules["hypothesis.extra.numpy"] = _hnp
 
 
 # ---------------------------------------------------------------------------

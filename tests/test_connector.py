@@ -31,6 +31,27 @@ def test_roundtrip_nested(kind):
     assert conn.metadata("k1") is None
 
 
+@pytest.mark.parametrize("kind", ["inline", "shm", "mooncake", "segment"])
+def test_roundtrip_keeps_bfloat16(kind):
+    """bf16 KV (a published-width PD payload) must come back as bf16,
+    not as the void dtype its ``dtype.str`` names."""
+    import jax.numpy as jnp
+
+    from repro.connector import shm_transport
+    kv = np.arange(12, dtype=np.float32).reshape(3, 4).astype(jnp.bfloat16)
+    payload = {"kv_k": kv, "n": 3}
+    if kind == "segment":                # the cross-process manifest path
+        _, manifest = shm_transport.write_segment(payload)
+        got = shm_transport.read_and_release(manifest)
+    else:
+        conn = make_connector(kind)
+        conn.send("k", payload)
+        got = conn.recv("k", timeout=1.0)
+        conn.release("k")
+    assert got["kv_k"].dtype == kv.dtype
+    np.testing.assert_array_equal(got["kv_k"], kv)
+
+
 @given(hnp.arrays(dtype=st.sampled_from([np.float32, np.int32, np.float16]),
                   shape=hnp.array_shapes(min_dims=1, max_dims=3,
                                          max_side=16)))

@@ -98,3 +98,24 @@ def test_engine_mixed_chunk_shapes_in_queue():
     shapes = {ev.req_id: ev.payload["latent"].shape for ev in done}
     assert shapes[0] == (4, 16)
     assert all(shapes[i] == (8, 16) for i in (1, 2, 3))
+
+
+def test_engine_finishes_a_stream_in_chunk_order():
+    """A stream's short last chunk sits in its own shape bucket; even when
+    that bucket is the largest, the chunk must not run (and complete the
+    request) before the request's earlier chunks."""
+    p = init_dit(CFG, jax.random.PRNGKey(0))
+    eng = DiffusionEngine("d", CFG, p, max_batch=4)
+    full = np.random.randn(6, 64).astype(np.float32)
+    short = np.random.randn(3, 64).astype(np.float32)
+    for c in range(3):                         # request 0: 2 full + 1 short
+        eng.enqueue(0, {"cond": short if c == 2 else full,
+                        "out_len": 4 if c == 2 else 8, "chunk_index": c,
+                        "is_last_chunk": c == 2})
+    for i in (1, 2):                           # single short requests
+        eng.enqueue(i, {"cond": short, "out_len": 4})
+    done = []
+    while eng.has_work:
+        done += eng.step()
+    assert [ev.chunk_index for ev in done if ev.req_id == 0] == [0, 1, 2]
+    assert sorted(ev.req_id for ev in done) == [0, 0, 0, 1, 2]

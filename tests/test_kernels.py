@@ -23,6 +23,7 @@ def _tol(dtype):
     (1, 128, 4, 4, 64),     # MHA
     (2, 256, 8, 2, 64),     # GQA 4:1
     (1, 128, 8, 1, 128),    # MQA
+    (1, 200, 4, 2, 64),     # length not a multiple of the block
 ])
 @pytest.mark.parametrize("causal,window", [(True, 0), (True, 64), (False, 0)])
 def test_flash_attention_sweep(b, s, nq, nkv, hd, causal, window, dtype):
@@ -36,6 +37,19 @@ def test_flash_attention_sweep(b, s, nq, nkv, hd, causal, window, dtype):
                                np.asarray(want, np.float32), **_tol(dtype))
 
 
+@pytest.mark.parametrize("sq,sk", [(200, 24), (40, 8), (64, 130)])
+def test_flash_attention_cross_ragged(sq, sk):
+    """DiT cross-attention shapes: query and key lengths differ and are not
+    multiples of the block; padding must not change the result."""
+    q = jax.random.normal(KEYS[0], (2, sq, 4, 32), jnp.float32)
+    k = jax.random.normal(KEYS[1], (2, sk, 4, 32), jnp.float32)
+    v = jax.random.normal(KEYS[2], (2, sk, 4, 32), jnp.float32)
+    got = flash_attention(q, k, v, causal=False, interpret=True)
+    want = ref.flash_attention(q, k, v, causal=False)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               **_tol(jnp.float32))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("b,nq,nkv,hd,page,pp", [
     (2, 8, 2, 64, 8, 4),
@@ -46,8 +60,8 @@ def test_flash_attention_sweep(b, s, nq, nkv, hd, causal, window, dtype):
 def test_paged_attention_sweep(b, nq, nkv, hd, page, pp, window, dtype):
     P = b * pp + 2
     q = jax.random.normal(KEYS[3], (b, nq, hd), dtype)
-    kp = jax.random.normal(KEYS[4], (P, page, nkv, hd), dtype)
-    vp = jax.random.normal(KEYS[5], (P, page, nkv, hd), dtype)
+    kp = jax.random.normal(KEYS[4], (P, nkv, page, hd), dtype)
+    vp = jax.random.normal(KEYS[5], (P, nkv, page, hd), dtype)
     bt = jax.random.permutation(KEYS[6], P)[:b * pp].reshape(b, pp)
     bt = bt.astype(jnp.int32)
     max_len = page * pp
@@ -63,8 +77,8 @@ def test_paged_attention_int8_dequant():
     b, nq, nkv, hd, page, pp = 2, 8, 2, 64, 8, 4
     P = b * pp + 2
     q = jax.random.normal(KEYS[3], (b, nq, hd), jnp.float32)
-    kf = jax.random.normal(KEYS[4], (P, page, nkv, hd), jnp.float32)
-    vf = jax.random.normal(KEYS[5], (P, page, nkv, hd), jnp.float32)
+    kf = jax.random.normal(KEYS[4], (P, nkv, page, hd), jnp.float32)
+    vf = jax.random.normal(KEYS[5], (P, nkv, page, hd), jnp.float32)
 
     def quant(x):
         s = jnp.max(jnp.abs(x), axis=-1) / 127.0 + 1e-8

@@ -244,3 +244,21 @@ def test_warm_seed_failure_degrades_to_cold_start():
     finally:
         rs.stop()
         rs.join(30.0)
+
+
+def test_jax_engine_process_replica_refused_on_device_parent(monkeypatch):
+    """Once the parent holds an accelerator, a spawned child that rebuilds
+    a JAX engine would land on the CPU: refuse at construction.  Host-only
+    engines may still be process-isolated."""
+    import jax
+
+    class DeviceEngine(StubEngine):
+        host_only = False                # stands in for a JAX engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = ServeConfig(stages={"s": StageConfig(isolation="process",
+                                                  engine_spec=STUB)})
+    with pytest.raises(ValueError, match="would run on the CPU"):
+        Orchestrator(_graph(), {"s": DeviceEngine("s")}, config=config)
+    orch = Orchestrator(_graph(), {"s": StubEngine("s")}, config=config)
+    assert orch._proc_replicas == {"s": 1}

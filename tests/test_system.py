@@ -1,11 +1,15 @@
 """End-to-end behaviour tests for the full disaggregated serving system."""
+import sys
+
 import jax
 import numpy as np
+import pytest
 
 from repro.baselines.monolithic import MonolithicQwenOmni
 from repro.configs.pipelines import build_qwen_omni
 from repro.core.orchestrator import Orchestrator
 from repro.core.request import Request
+from repro.launch import serve
 from repro.launch.serve import build_single_arch
 from repro.models.dit import DiTConfig, init_dit
 
@@ -104,3 +108,20 @@ def test_int8_kv_cache_end_to_end():
     rel = float(jnp.max(jnp.abs(lo[:, 0] - full[:, 8]))
                 / jnp.max(jnp.abs(full[:, 8])))
     assert rel < 0.05, rel
+
+
+@pytest.mark.parametrize("online", [False, True])
+@pytest.mark.parametrize("fail", [False, True])
+def test_serve_exit_status(monkeypatch, online, fail):
+    """launch/serve.py exits 1 when a request fails (here: inputs the AR
+    stage rejects at admission), 0 when every request is served."""
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    if fail:
+        monkeypatch.setattr(serve, "_make_inputs",
+                            lambda pipeline, rng: {"bogus": np.zeros(3)})
+    argv = ["serve", "--arch", "internlm2_1_8b", "--requests", "2",
+            "--max-batch", "2", "--max-new", "2"]
+    if online:
+        argv += ["--online", "--rate", "100"]
+    monkeypatch.setattr(sys, "argv", argv)
+    assert serve.main() == int(fail)

@@ -1,0 +1,96 @@
+"""Ahead-of-time compiles of the main path's Pallas kernels for one TPU v5e.
+
+The TPU compiler is installed without a chip: each test lowers a kernel
+at real widths for a described v5e device and compiles it, which catches
+what interpret mode cannot (block shapes the tiling refuses, slices not
+provably aligned, too much VMEM).  Nothing runs.
+
+This is the only test file that describes the chip.  The topology is
+built inside a module fixture, never at import, so every xdist worker
+collects the same tests and only the worker given this file loads the
+TPU library.  The persistent compilation cache is off around the
+compiles: an entry written for a described chip cannot be read back.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.base import get_config
+from repro.configs.pipelines import _kv
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.mamba_scan import mamba1_scan
+from repro.kernels.paged_attention import paged_attention
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    cache_on = jax.config.jax_enable_compilation_cache
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")     # no compiler logs in /tmp
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:                   # noqa: BLE001
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield desc
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache_on)
+            compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_paged_decode_compiles_at_internlm2_widths(one_chip, kv_dtype):
+    cfg = get_config("internlm2_1_8b")
+    kv = _kv(4)
+    b, nkv, hd = 4, cfg.num_kv_heads, cfg.head_dim
+    pool = ((kv.num_pages, nkv, kv.page_size, hd), jnp.dtype(kv_dtype))
+    shapes = [((b, cfg.num_heads, hd), jnp.bfloat16), pool, pool,
+              ((b, kv.max_pages_per_seq), jnp.int32), ((b,), jnp.int32)]
+    if kv_dtype == "int8":
+        scales = ((kv.num_pages, nkv, kv.page_size), jnp.float32)
+        _compile(lambda q, k, v, bt, sl, ks, vs: paged_attention(
+            q, k, v, bt, sl, k_scale_pages=ks, v_scale_pages=vs),
+            one_chip, *shapes, scales, scales)
+    else:
+        _compile(paged_attention, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("sq,sk,nq,nkv,hd,causal", [
+    (512, 512, 16, 8, 128, True),     # prefill at internlm2 head widths
+    (32, 16, 4, 4, 32, False),        # DiT vocoder self / cross attention
+    (64, 32, 4, 4, 32, False),
+    (200, 24, 4, 4, 32, False),       # above 128, not a multiple of 128
+])
+def test_flash_attention_compiles(one_chip, sq, sk, nq, nkv, hd, causal):
+    _compile(lambda q, k, v: flash_attention(q, k, v, causal=causal),
+             one_chip, ((2, sq, nq, hd), jnp.bfloat16),
+             ((2, sk, nkv, hd), jnp.bfloat16),
+             ((2, sk, nkv, hd), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("s", [1, 200])      # decode step, ragged prefill
+def test_mamba1_scan_compiles_at_falcon_mamba_widths(one_chip, s):
+    cfg = get_config("falcon_mamba_7b")
+    di, n = cfg.d_inner, cfg.ssm_state
+    assert (di, n) == (8192, 16)
+    act = jnp.dtype(cfg.dtype)
+    _compile(mamba1_scan, one_chip, ((1, s, di), act), ((1, s, di), act),
+             ((di, n), jnp.float32), ((1, s, n), act), ((1, s, n), act),
+             ((di,), jnp.float32), ((1, di, n), jnp.float32))
