@@ -45,6 +45,8 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 
+from repro.core.tracing import span
+
 
 class TransferTimeout(TimeoutError):
     """``recv(key, timeout)`` waited out its timeout.
@@ -126,14 +128,15 @@ class Connector:
     # -- async channel API -------------------------------------------------
     def send(self, key: str, payload: Any) -> TransferHandle:
         """Publish a payload under ``key`` and wake any waiting ``recv``."""
-        t0 = time.perf_counter()
-        nbytes = payload_nbytes(payload)
-        entry, modeled = self._pack(payload)         # heavy copy, unlocked
-        with self._ready:
-            self._publish(key, entry)
-            self._meta[key] = {"nbytes": nbytes, "t_put": t0}
-            self.stats.record(nbytes, time.perf_counter() - t0, modeled)
-            self._ready.notify_all()
+        with span("conn", "send"):
+            t0 = time.perf_counter()
+            nbytes = payload_nbytes(payload)
+            entry, modeled = self._pack(payload)     # heavy copy, unlocked
+            with self._ready:
+                self._publish(key, entry)
+                self._meta[key] = {"nbytes": nbytes, "t_put": t0}
+                self.stats.record(nbytes, time.perf_counter() - t0, modeled)
+                self._ready.notify_all()
         return TransferHandle(key=key, nbytes=nbytes, t_send=t0)
 
     def recv(self, key: str, timeout: Optional[float] = None) -> Any:
@@ -142,23 +145,24 @@ class Connector:
         ``timeout=None`` waits forever; ``timeout=0`` is a non-blocking
         probe. Raises ``TimeoutError`` if the key never shows up.
         """
-        t0 = time.perf_counter()
-        deadline = None if timeout is None else t0 + timeout
-        with self._ready:
-            # the while condition re-checks after every wait, so a publish
-            # racing the timeout expiry is never dropped
-            while key not in self._meta:
-                remaining = (None if deadline is None
-                             else deadline - time.perf_counter())
-                if remaining is not None and remaining <= 0:
-                    raise TransferTimeout(key, connector=self.name,
-                                          timeout=timeout)
-                self._ready.wait(remaining)
-            entry = self._fetch(key)
-        payload, modeled = self._unpack(entry)       # heavy copy, unlocked
-        with self._lock:
-            self.stats.wall_time += time.perf_counter() - t0
-            self.stats.modeled_time += modeled
+        with span("conn", "recv"):
+            t0 = time.perf_counter()
+            deadline = None if timeout is None else t0 + timeout
+            with self._ready:
+                # the while condition re-checks after every wait, so a
+                # publish racing the timeout expiry is never dropped
+                while key not in self._meta:
+                    remaining = (None if deadline is None
+                                 else deadline - time.perf_counter())
+                    if remaining is not None and remaining <= 0:
+                        raise TransferTimeout(key, connector=self.name,
+                                              timeout=timeout)
+                    self._ready.wait(remaining)
+                entry = self._fetch(key)
+            payload, modeled = self._unpack(entry)   # heavy copy, unlocked
+            with self._lock:
+                self.stats.wall_time += time.perf_counter() - t0
+                self.stats.modeled_time += modeled
         return payload
 
     def release(self, key: str) -> None:

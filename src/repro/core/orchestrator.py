@@ -248,7 +248,6 @@ class Orchestrator:
         #: stream of finished Requests, in completion order — the online
         #: front-end consumes this while the backend keeps serving
         self.completions: "queue.Queue[Request]" = queue.Queue()
-        self._transfer_log: List[dict] = []
         self._lock = threading.RLock()
         # ---- threaded backend state ----
         self._workers: Dict[str, ReplicaSet] = {}
@@ -306,7 +305,6 @@ class Orchestrator:
                 with self._lock:
                     self._deferred.append((src, request))
             else:
-                request.mark_stage_start(src)
                 self.engines[src].enqueue(
                     request.req_id, request.inputs, self._sp(request),
                     request.data)
@@ -557,8 +555,6 @@ class Orchestrator:
         conn = self.connectors[edge.connector]
         eid = StageGraph.edge_id(edge)
         key = f"{eid}/{req.req_id}/{ev.chunk_index}"
-        self._transfer_log.append({
-            "edge": eid, "connector": edge.connector, "req_id": req.req_id})
         if self._started:
             # upstream side publishes; the destination worker receives,
             # deserializes and applies the transfer in ITS thread
@@ -622,7 +618,6 @@ class Orchestrator:
             return
         if inputs is None:
             return
-        req.mark_stage_start(edge.dst)
         self.engines[edge.dst].enqueue(req.req_id, inputs, self._sp(req),
                                        req.data)
 
@@ -636,8 +631,6 @@ class Orchestrator:
             # fault isolation: the failing stage input killed one request
             self._fail(req, str(ev.payload.get("error", "stage error")))
             return
-        if ev.kind == "finished":
-            req.mark_stage_end(stage)
         for edge in self.graph.out_edges(stage):
             if ev.kind == "chunk" and not edge.streaming:
                 continue                      # non-streaming edges wait
@@ -660,7 +653,6 @@ class Orchestrator:
                 req.first_output_time = time.perf_counter()
             if ev.kind == "finished" or (ev.kind == "chunk" and ev.is_last):
                 req.outputs.setdefault(stage, []).append(ev.payload)
-                req.mark_stage_end(stage)
                 outs.discard(stage)
                 done = not outs
             elif ev.kind == "chunk":

@@ -504,7 +504,6 @@ class ProcessStageWorker:
             req.note_queue_delay(self.name, delay)
             self.metrics.note_filtered()
             return
-        req.mark_stage_start(self.name)
         # the child-side queue is the bounded half of the inbox: wait for
         # ship credit so backpressure still propagates through submit()
         while self.pending >= self.capacity:

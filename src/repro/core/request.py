@@ -26,7 +26,6 @@ class Request:
     arrival_time: float = field(default_factory=time.perf_counter)
     completion_time: Optional[float] = None
     first_output_time: Optional[float] = None   # TTFT of the FINAL output
-    stage_spans: Dict[str, List[float]] = field(default_factory=dict)
     # per-stage queueing delays (submit -> engine admission), seconds; a
     # stage fed by a streaming edge collects one sample per chunk
     queue_delays: Dict[str, List[float]] = field(default_factory=dict)
@@ -34,24 +33,11 @@ class Request:
     outputs: Dict[str, Any] = field(default_factory=dict)
     failed: Optional[str] = None
 
-    def mark_stage_start(self, stage: str) -> None:
-        self.stage_spans.setdefault(stage, [time.perf_counter(), None])
-
-    def mark_stage_end(self, stage: str) -> None:
-        span = self.stage_spans.setdefault(stage, [time.perf_counter(), None])
-        span[1] = time.perf_counter()
-
     @property
     def jct(self) -> Optional[float]:
         if self.completion_time is None:
             return None
         return self.completion_time - self.arrival_time
-
-    def stage_time(self, stage: str) -> float:
-        span = self.stage_spans.get(stage)
-        if not span or span[1] is None:
-            return 0.0
-        return span[1] - span[0]
 
     def note_queue_delay(self, stage: str, delay: float) -> None:
         self.queue_delays.setdefault(stage, []).append(delay)

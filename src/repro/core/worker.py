@@ -261,6 +261,9 @@ class StageWorker:
 
     # -- worker thread -----------------------------------------------------
     def _admit(self, item: StageInput) -> None:
+        # imported here: this module stays jax-free for the spawned
+        # children of ``proc_worker``, which never admit through it
+        from repro.core.tracing import span
         req = item.request
         delay = time.perf_counter() - item.t_submit
         self.metrics.note_admit(delay)
@@ -284,14 +287,15 @@ class StageWorker:
             else:
                 self._last_seq[req.req_id] = item.seq
         try:
-            inputs = item.inputs
-            if item.resolve is not None:
-                inputs = item.resolve()
-            if inputs is None:               # transfer fn filtered this event
-                self.metrics.note_filtered()
-                return
-            req.mark_stage_start(self.name)
-            self.engine.enqueue(req.req_id, inputs, item.sampling, req.data)
+            with span(self.name, "admit"):
+                inputs = item.inputs
+                if item.resolve is not None:
+                    inputs = item.resolve()
+                if inputs is None:           # transfer fn filtered this event
+                    self.metrics.note_filtered()
+                    return
+                self.engine.enqueue(req.req_id, inputs, item.sampling,
+                                    req.data)
         except Exception as e:               # noqa: BLE001 — fault isolation
             self.metrics.note_error()
             self.emit(self.name, StageEvent(

@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.request import StageEvent
+from repro.core.tracing import span
 from repro.engine.kv_cache import (PagedKVConfig, embed_prefix_keys,
                                    hash_embed_blocks, hash_token_blocks,
                                    token_prefix_keys)
@@ -47,7 +48,6 @@ class _ReqRuntime:
     last_logits: Optional[jax.Array] = None
     streamed: int = 0
     chunk_index: int = 0
-    t_first_sched: Optional[float] = None
     kv_seed: Optional[tuple] = None              # (k, v, prompt_len) — PD
 
 
@@ -188,6 +188,15 @@ class AREngine:
         return dict(self.scheduler.prefix_stats)
 
     @property
+    def sched_stats(self) -> Dict[str, Any]:
+        """The scheduler's counters (``Scheduler.sched_stats``: steps,
+        admissions, reserved and used page-steps) and its recent
+        ``admission_waits`` as (req_id, seconds), oldest first."""
+        sch = self.scheduler
+        return dict(sch.sched_stats,
+                    admission_waits=list(sch.admission_waits))
+
+    @property
     def has_work(self) -> bool:
         return self.scheduler.has_work
 
@@ -325,7 +334,8 @@ class AREngine:
             if self.emit_kv and self._paged:
                 seq = self.scheduler.running[req_id]
                 bt = self.scheduler.tables.row(req_id)
-                k, v = self.runner.extract_kv(bt, seq.pos)
+                with span(self.name, "extract_kv"):
+                    k, v = self.runner.extract_kv(bt, seq.pos)
                 payload.update({"kv_k": k, "kv_v": v,
                                 "prompt_len": seq.pos})
             events.append(StageEvent(req_id, "finished", payload,
@@ -382,10 +392,43 @@ class AREngine:
     def step(self) -> List[StageEvent]:
         t0 = time.perf_counter()
         events: List[StageEvent] = []
-        plan = self.scheduler.schedule()
-        # preemption (recompute mode): the victim's generated tokens (minus
-        # the unwritten last one) join its prompt for re-prefill
-        for rid in plan.preempted:
+        with span(self.name, "step"):
+            with span(self.name, "schedule"):
+                plan = self.scheduler.schedule()
+                self._extend_preempted(plan.preempted)
+            # prefix cache copy-on-write: a request whose whole page-aligned
+            # prompt hit the cache gets a private copy of the final shared
+            # page before recomputing (and rewriting) its last token
+            if plan.cow_pairs:
+                with span(self.name, "cow"):
+                    self.runner.copy_pages([s for s, _ in plan.cow_pairs],
+                                           [d for _, d in plan.cow_pairs])
+            # PD disaggregation: inject transferred KV for newly admitted
+            # pre-filled requests before their first decode step
+            seeded = [rid for rid in plan.admitted
+                      if rid in self._rt and self._rt[rid].kv_seed is not None]
+            if seeded:
+                with span(self.name, "inject_kv"):
+                    for rid in seeded:
+                        rt = self._rt[rid]
+                        k, v, n = rt.kv_seed
+                        self.runner.inject_kv(
+                            k, v, self.scheduler.tables.row(rid), n)
+                        rt.kv_seed = None
+            if not plan.prefill_chunks and not plan.decode_req_ids:
+                return events
+            self.steps += 1
+            # prefill chunks, one request-chunk at a time
+            for ch in plan.prefill_chunks:
+                self._prefill_chunk(ch, events)
+            self._decode(plan.decode_req_ids, events)
+        self.busy_time += time.perf_counter() - t0
+        return events
+
+    def _extend_preempted(self, preempted: List[int]) -> None:
+        """Preemption (recompute mode): the victim's generated tokens
+        (minus the unwritten last one) join its prompt for re-prefill."""
+        for rid in preempted:
             rt = self._rt.get(rid)
             if rt is None or len(rt.tokens) < 1:
                 continue
@@ -397,29 +440,11 @@ class AREngine:
             if len(gen):
                 rt.prompt_embeds = np.concatenate(
                     [rt.prompt_embeds, np.asarray(self.runner.embed(gen))], 0)
-        # prefix cache copy-on-write: a request whose whole page-aligned
-        # prompt hit the cache gets a private copy of the final shared page
-        # before recomputing (and rewriting) its last token
-        if plan.cow_pairs:
-            self.runner.copy_pages([s for s, _ in plan.cow_pairs],
-                                   [d for _, d in plan.cow_pairs])
-        # PD disaggregation: inject transferred KV for newly admitted
-        # pre-filled requests before their first decode step
-        for rid in plan.admitted:
-            rt = self._rt.get(rid)
-            if rt is not None and rt.kv_seed is not None:
-                k, v, n = rt.kv_seed
-                self.runner.inject_kv(
-                    k, v, self.scheduler.tables.row(rid), n)
-                rt.kv_seed = None
-        if not plan.prefill_chunks and not plan.decode_req_ids:
-            return events
-        self.steps += 1
 
-        # ---- prefill chunks (one request-chunk at a time) --------------
-        for ch in plan.prefill_chunks:
-            rt = self._rt[ch.req_id]
-            seq = self.scheduler.running[ch.req_id]
+    def _prefill_chunk(self, ch, events: List[StageEvent]) -> None:
+        rt = self._rt[ch.req_id]
+        seq = self.scheduler.running[ch.req_id]
+        with span(self.name, "prefill"):
             emb = rt.prompt_embeds[ch.start:ch.start + ch.length]
             if self._paged:
                 # pad to the chunk bucket so jit shapes stay few
@@ -436,56 +461,69 @@ class AREngine:
                 last_logits = logits[-1]
                 hidden = None
             self.scheduler.note_prefill(ch.req_id, ch.length)
-            if not seq.in_prefill and seq.resumed:
-                # resumed after preemption: the next token was already
-                # sampled before eviction — decode continues from it
-                seq.resumed = False
-                continue
-            if not seq.in_prefill:
-                # prompt complete: sample the first token from prefill logits
-                tok = self._sample(ch.req_id, last_logits)
-                rt.tokens.append(tok)
-                if self.collect_hidden and hidden is not None:
-                    rt.hiddens.append(np.asarray(hidden[ch.length - 1]))
-                finished = self.scheduler.note_sampled(ch.req_id, tok)
-                self._emit_progress(ch.req_id, events, finished)
-                if finished:
-                    self._release(ch.req_id)
+        if not seq.in_prefill and seq.resumed:
+            # resumed after preemption: the next token was already
+            # sampled before eviction — decode continues from it
+            seq.resumed = False
+            return
+        if seq.in_prefill:
+            return
+        # prompt complete: sample the first token from prefill logits
+        with span(self.name, "first_token"):
+            tok = self._sample(ch.req_id, last_logits)
+        if self.collect_hidden and hidden is not None:
+            with span(self.name, "hidden_to_host"):
+                rt.hiddens.append(np.asarray(hidden[ch.length - 1]))
+        with span(self.name, "emit"):
+            rt.tokens.append(tok)
+            finished = self.scheduler.note_sampled(ch.req_id, tok)
+            self._emit_progress(ch.req_id, events, finished)
+            if finished:
+                self._release(ch.req_id)
 
-        # ---- batched decode --------------------------------------------
-        dec_ids = [r for r in plan.decode_req_ids
-                   if r in self.scheduler.running
-                   and not self.scheduler.running[r].finished]
-
-        # ---- speculative decode (n-gram draft + chunk verify) -----------
+    def _decode(self, req_ids: List[int], events: List[StageEvent]) -> None:
+        """One batched decode over the scheduled requests still running
+        (speculative decode takes the requests it can draft for)."""
+        running = self.scheduler.running
+        spec_done = set()
         if self.spec_ngram and self._paged and self.preprocess is None:
-            for rid in list(dec_ids):
-                if self._spec_decode_one(rid, events):
-                    dec_ids.remove(rid)
-        if dec_ids:
+            # n-gram draft + chunk verify, one request at a time
+            with span(self.name, "spec_decode"):
+                spec_done = {r for r in req_ids
+                             if r in running and not running[r].finished
+                             and self._spec_decode_one(r, events)}
+        with span(self.name, "decode_inputs"):
+            dec_ids = [r for r in req_ids if r not in spec_done
+                       and r in running and not running[r].finished]
+            if not dec_ids:
+                return
             B = self.max_batch
-            d = self.cfg.d_model
-            embeds = np.zeros((B, 1, d), np.float32)
+            embeds = np.zeros((B, 1, self.cfg.d_model), np.float32)
             positions = np.zeros((B,), np.int32)
             active = np.zeros((B,), bool)
             tables = np.zeros((B, self.kv.max_pages_per_seq), np.int32)
             slot_of = {}
             for rid in dec_ids:
-                seq = self.scheduler.running[rid]
+                seq = running[rid]
                 s = seq.slot
                 slot_of[rid] = s
                 embeds[s, 0] = self._decode_embed_row(rid)
                 positions[s] = seq.pos
                 active[s] = True
                 tables[s] = self.scheduler.tables.row(rid)
-            dt = jnp.dtype(self.cfg.dtype)
+        with span(self.name, "decode"):
             logits, hidden = self.runner.decode(
-                jnp.asarray(embeds, dt), tables, positions, active)
-            hidden_np = (np.asarray(hidden) if hidden is not None else None)
+                jnp.asarray(embeds, jnp.dtype(self.cfg.dtype)), tables,
+                positions, active)
+        hidden_np = None
+        if hidden is not None:
+            with span(self.name, "hidden_to_host"):
+                hidden_np = np.asarray(hidden)
+        with span(self.name, "sample"):
             # batch sampling: one jitted call per (temperature, top_k) group
             groups: Dict[tuple, List[int]] = {}
             for rid in dec_ids:
-                sp = self.scheduler.running[rid].sampling
+                sp = running[rid].sampling
                 groups.setdefault((sp.temperature, sp.top_k), []).append(rid)
             sampled: Dict[int, int] = {}
             for (temp, tk), rids in groups.items():
@@ -495,18 +533,15 @@ class AREngine:
                 self._key, sk = jax.random.split(self._key)
                 toks = np.asarray(sample_tokens(logits[rows], temp, tk, sk))
                 sampled.update(zip(rids, toks[:len(rids)].tolist()))
+        with span(self.name, "emit"):
             for rid in dec_ids:
-                s = slot_of[rid]
                 self.scheduler.note_decode_written(rid)
                 tok = int(sampled[rid])
                 rt = self._rt[rid]
                 rt.tokens.append(tok)
                 if self.collect_hidden and hidden_np is not None:
-                    rt.hiddens.append(hidden_np[s])
+                    rt.hiddens.append(hidden_np[slot_of[rid]])
                 finished = self.scheduler.note_sampled(rid, tok)
                 self._emit_progress(rid, events, finished)
                 if finished:
                     self._release(rid)
-
-        self.busy_time += time.perf_counter() - t0
-        return events

@@ -17,6 +17,7 @@ tests/test_kv_prefix_cache.py):
 """
 from __future__ import annotations
 
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional, Tuple
@@ -42,6 +43,7 @@ class SeqState:
     # radix index compares these at the diverging block for partial hits
     prefix_keys: List[BlockKey] = field(default_factory=list)
     cached_tokens: int = 0             # prompt tokens served from the cache
+    t_enqueued: float = 0.0            # perf_counter when it joined waiting
 
     @property
     def in_prefill(self) -> bool:
@@ -101,6 +103,14 @@ class Scheduler:
                              "cached_tokens": 0, "computed_tokens": 0,
                              "full_block_tokens": 0, "partial_tokens": 0,
                              "partial_hits": 0}
+        # occupancy counters (surfaced by the engine as ``sched_stats``):
+        # per schedule() call, the pages in each running request's block
+        # table (reserved) and the pages its written KV fills (used)
+        self.sched_stats = {"steps": 0, "admitted": 0,
+                            "reserved_page_steps": 0, "used_page_steps": 0}
+        #: (req_id, seconds from joining ``waiting`` to admission) of the
+        #: most recent admissions, oldest first
+        self.admission_waits: Deque[Tuple[int, float]] = deque(maxlen=4096)
 
     # ------------------------------------------------------------------
     def add(self, req_id: int, prompt_len: int, sampling: SamplingParams,
@@ -108,7 +118,8 @@ class Scheduler:
             prefix_keys: Optional[List[BlockKey]] = None) -> None:
         self.waiting.append(SeqState(req_id, prompt_len, sampling,
                                      block_hashes=block_hashes or [],
-                                     prefix_keys=prefix_keys or []))
+                                     prefix_keys=prefix_keys or [],
+                                     t_enqueued=time.perf_counter()))
 
     def set_hashes(self, req_id: int, hashes: List[BlockHash],
                    keys: Optional[List[BlockKey]] = None) -> None:
@@ -127,7 +138,8 @@ class Scheduler:
         engine injects the transferred KV on admission."""
         self.waiting.append(SeqState(req_id, prompt_len, sampling,
                                      prefill_done=prompt_len,
-                                     generated=1, pos=prompt_len))
+                                     generated=1, pos=prompt_len,
+                                     t_enqueued=time.perf_counter()))
 
     def _admission_pages(self, seq: SeqState) -> int:
         """Pages reserved at admission. With preemption the pool grows
@@ -224,6 +236,9 @@ class Scheduler:
         self.tables.set(seq.req_id, cached + fresh)
         self.running[seq.req_id] = seq
         plan.admitted.append(seq.req_id)
+        self.sched_stats["admitted"] += 1
+        self.admission_waits.append(
+            (seq.req_id, time.perf_counter() - seq.t_enqueued))
         return True
 
     def _try_admit(self, plan: StepPlan) -> None:
@@ -262,6 +277,7 @@ class Scheduler:
         if victim.generated >= 1:
             victim.prompt_len += victim.generated - 1
             victim.resumed = True
+        victim.t_enqueued = time.perf_counter()
         self.waiting.appendleft(victim)
         self.preemptions += 1
 
@@ -295,6 +311,11 @@ class Scheduler:
         self._try_admit(plan)
         if self.enable_preemption:
             self._ensure_decode_capacity(plan)
+        st, page = self.sched_stats, self.kv.page_size
+        st["steps"] += 1
+        for seq in self.running.values():
+            st["reserved_page_steps"] += len(self.tables.tables[seq.req_id])
+            st["used_page_steps"] += pages_for(seq.pos, page)
         budget = self.token_budget
         # decodes first (latency-critical; never dropped)
         for seq in self.running.values():
