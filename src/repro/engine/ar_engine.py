@@ -197,6 +197,15 @@ class AREngine:
                     admission_waits=list(sch.admission_waits))
 
     @property
+    def kernel_stats(self) -> Dict[str, int]:
+        """The paged decode kernel's grid counters
+        (``PagedRunner.kernel_stats``: calls, blocks in the grid, blocks
+        that held context); empty for a recurrent-state stage."""
+        if not self._paged:
+            return {}
+        return dict(self.runner.kernel_stats)
+
+    @property
     def has_work(self) -> bool:
         return self.scheduler.has_work
 

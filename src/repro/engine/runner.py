@@ -25,6 +25,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.engine.kv_cache import PagedKVConfig, init_kv_pages
 from repro.kernels import ops, ref
+from repro.kernels import paged_attention as pa
 from repro.models import layers as L
 from repro.models import moe as moe_mod
 from repro.models import transformer as T
@@ -57,6 +58,15 @@ class PagedRunner:
             self._prefill_impl, donate_argnums=(1, 2),
             static_argnames=())
         self._decode_jit = jax.jit(self._decode_impl, donate_argnums=(1, 2))
+        # how much of the paged kernel's grid does work (host arithmetic on
+        # the decode batch, whatever the backend): ``blocks_in_grid`` counts
+        # its B x ceil(pp / ppb) steps per call, ``blocks_live`` those that
+        # hold context and so copy and compute
+        self._ppb = pa.pages_per_block(
+            kv.page_size, kv.max_pages_per_seq, cfg.num_kv_heads,
+            cfg.head_dim, self.k_pages.dtype.itemsize, self.quant)
+        self.kernel_stats = {"calls": 0, "blocks_in_grid": 0,
+                             "blocks_live": 0}
         # host-side copy of the embedding table: avoids retracing an eager
         # gather for every prompt length (hot path for token->embed lookups)
         self._embed_np = np.asarray(params["embed"], np.float32)
@@ -250,6 +260,15 @@ class PagedRunner:
         return logits, hidden, k_pages, v_pages, k_scales, v_scales
 
     def decode(self, embeds, block_tables, positions, active):
+        window = (self.cfg.sliding_window if self.cfg.attn_variant == "swa"
+                  else 0)
+        seq_lens = np.where(active, np.asarray(positions) + 1, 0)
+        st = self.kernel_stats
+        st["calls"] += 1
+        st["blocks_in_grid"] += len(seq_lens) * -(
+            -self.kv.max_pages_per_seq // self._ppb)
+        st["blocks_live"] += pa.live_block_count(
+            seq_lens, self._ppb * self.kv.page_size, window)
         (logits, hidden, self.k_pages, self.v_pages, self.k_scales,
          self.v_scales) = self._decode_jit(
             self.params, self.k_pages, self.v_pages, self.k_scales,

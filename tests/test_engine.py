@@ -14,6 +14,7 @@ from repro.configs.pipelines import tiny_lm
 from repro.engine.ar_engine import AREngine
 from repro.engine.kv_cache import PagedKVConfig
 from repro.engine.sampling import SamplingParams
+from repro.kernels.paged_attention import pages_per_block
 from repro.models import transformer as T
 
 
@@ -201,3 +202,32 @@ def test_eos_stops_generation(lm):
         if not eng.has_work:
             break
     assert len(fin.payload["tokens"]) == 1
+
+
+@pytest.mark.parametrize("window,positions,live", [
+    # seq_lens 256 | 257 | 601 | inactive: 1 + 2 + 3 blocks of 256 tokens
+    (0, (255, 256, 600, 7), 1 + 2 + 3),
+    # a 100-token window: 500-599 -> blocks 1, 2; 0-40 -> 0; 200-299 -> 0, 1
+    (100, (599, 40, 299, 7), 2 + 1 + 2),
+])
+def test_kernel_stats_count_live_blocks(lm, window, positions, live):
+    """``kernel_stats`` counts the paged kernel's grid, B x ceil(pp / ppb)
+    steps a call, and the steps whose block holds context, on a
+    hand-built decode batch with an inactive slot."""
+    cfg, params = lm
+    if window:
+        cfg = cfg.replace(attn_variant="swa", sliding_window=window)
+    kv = PagedKVConfig(num_pages=8, page_size=16, max_pages_per_seq=40)
+    assert pages_per_block(16, 40, cfg.num_kv_heads, cfg.head_dim, 4) == 16
+    eng = _engine(cfg, params, kv=kv)
+    assert eng.kernel_stats == {"calls": 0, "blocks_in_grid": 0,
+                                "blocks_live": 0}
+    b = eng.max_batch
+    embeds = jnp.zeros((b, 1, cfg.d_model), jnp.float32)
+    tables = np.zeros((b, kv.max_pages_per_seq), np.int32)
+    active = np.array([True, True, True, False])
+    for n in (1, 2):
+        eng.runner.decode(embeds, tables, np.asarray(positions, np.int32),
+                          active)
+        assert eng.kernel_stats == {"calls": n, "blocks_in_grid": n * b * 3,
+                                    "blocks_live": n * live}

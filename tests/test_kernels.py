@@ -8,7 +8,7 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mamba_scan import mamba1_scan
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import pages_per_block, paged_attention
 
 KEYS = jax.random.split(jax.random.PRNGKey(7), 16)
 
@@ -98,6 +98,66 @@ def test_paged_attention_int8_dequant():
     # and close to the unquantized result
     np.testing.assert_allclose(np.asarray(got), np.asarray(exact),
                                rtol=0.05, atol=0.05)
+
+
+# Several blocks per sequence: at page 16, hd 128 a block is 16 pages
+# (256 tokens), so pp = 40 makes 3 blocks, the last with 8 of 16 slots.
+_BLK = dict(b=3, nq=4, nkv=2, hd=128, page=16, pp=40)
+
+
+@pytest.mark.parametrize("case,seq_lens,window,dtype,poison", [
+    ("inactive_slot", (0, 300, 17), 0, jnp.float32, False),
+    ("block_edge", (256, 257, 512), 0, jnp.float32, False),
+    ("block_edge_bf16", (256, 257, 512), 0, jnp.bfloat16, False),
+    ("ragged_last_block", (640, 630, 513), 0, jnp.float32, False),
+    ("window_skips_blocks", (600, 300, 40), 100, jnp.float32, True),
+    ("int8_blocks", (600, 257, 1), 0, jnp.int8, True),
+    ("poisoned_tail", (300, 17, 0), 0, jnp.float32, True),
+])
+def test_paged_attention_blocks(case, seq_lens, window, dtype, poison):
+    """The blocked grid against the oracle: sequences ending on and just
+    past a block edge, a last block that ``pp`` fills only in part, a
+    window with whole blocks before it, int8 pools across blocks, and an
+    inactive slot (finite zeros).  With ``poison``, every table entry of a
+    page that holds no context points at a page of NaN (NaN scales for
+    int8): the output stays equal to the oracle's on a clean table, so no
+    such page is read."""
+    b, nq, nkv, hd, page, pp = (_BLK[k] for k in
+                                ("b", "nq", "nkv", "hd", "page", "pp"))
+    quant = dtype == jnp.int8
+    ppb = pages_per_block(page, pp, nkv, hd, jnp.dtype(dtype).itemsize,
+                          quant)
+    assert ppb * page == 256 and pp % ppb
+    P = b * pp + 1
+    poison_page = P - 1
+    q = jax.random.normal(KEYS[3], (b, nq, hd), jnp.float32)
+    kv = jax.random.normal(KEYS[4], (2, P, nkv, page, hd), jnp.float32)
+    scales = {}
+    if quant:
+        s = jnp.max(jnp.abs(kv), axis=-1) / 127.0 + 1e-8
+        pools = jnp.round(kv / s[..., None]).astype(jnp.int8)
+        s = s.at[:, poison_page].set(jnp.nan)
+        scales = dict(k_scale_pages=s[0], v_scale_pages=s[1])
+    else:
+        pools = kv.astype(dtype).at[:, poison_page].set(jnp.nan)
+    bt = jax.random.permutation(KEYS[6], P - 1).reshape(b, pp)
+    bt = bt.astype(jnp.int32)
+    sl = jnp.asarray(seq_lens, jnp.int32)
+    slot = jnp.arange(pp)[None, :]
+    held = slot * page < sl[:, None]
+    if window:
+        held &= (slot + 1) * page > sl[:, None] - window
+    table = jnp.where(held, bt, poison_page) if poison else bt
+    got = paged_attention(q, pools[0], pools[1], table, sl, window=window,
+                          interpret=True, **scales)
+    want = ref.paged_attention(q, pools[0], pools[1], bt, sl, window=window,
+                               **scales)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    live = np.asarray(sl) > 0
+    np.testing.assert_array_equal(got[~live], 0.0)
+    tol = _tol(jnp.bfloat16 if dtype == jnp.bfloat16 else jnp.float32)
+    np.testing.assert_allclose(got[live], want[live], **tol)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

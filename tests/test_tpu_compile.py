@@ -17,7 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import get_config
-from repro.configs.pipelines import _kv
+from repro.configs.pipelines import _kv, tiny_lm
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mamba_scan import mamba1_scan
 from repro.kernels.paged_attention import paged_attention
@@ -70,6 +70,42 @@ def test_paged_decode_compiles_at_internlm2_widths(one_chip, kv_dtype):
             one_chip, *shapes, scales, scales)
     else:
         _compile(paged_attention, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("arch,batch,pages,pp", [
+    ("internlm2_1_8b", 8, 1600, 408),    # the PD decode stage: GQA 16/8
+    ("qwen1_5_4b", 16, 560, 128),        # the unified engine: MHA 20/20
+])
+def test_paged_decode_compiles_at_cell_shapes(one_chip, arch, batch, pages,
+                                              pp, kv_dtype):
+    """The blocked kernel at the benchmark cells' decode shapes, so the
+    VMEM of the block size it picks is checked by the chip's compiler."""
+    cfg = get_config(arch)
+    nkv, hd, page = cfg.num_kv_heads, cfg.head_dim, 16
+    pool = ((pages, nkv, page, hd), jnp.dtype(kv_dtype))
+    shapes = [((batch, cfg.num_heads, hd), jnp.bfloat16), pool, pool,
+              ((batch, pp), jnp.int32), ((batch,), jnp.int32)]
+    if kv_dtype == "int8":
+        scales = ((pages, nkv, page), jnp.float32)
+        _compile(lambda q, k, v, bt, sl, ks, vs: paged_attention(
+            q, k, v, bt, sl, k_scale_pages=ks, v_scale_pages=vs),
+            one_chip, *shapes, scales, scales)
+    else:
+        _compile(paged_attention, one_chip, *shapes)
+
+
+def test_paged_decode_compiles_at_tiny_widths(one_chip):
+    """The tiny pipeline stages' heads (32 lanes, float32 pools) are
+    narrower than a lane row, which a page copy must move whole."""
+    cfg = tiny_lm("t")
+    kv = _kv(8)
+    nkv, hd = cfg.num_kv_heads, cfg.head_dim
+    assert hd % 128
+    pool = ((kv.num_pages, nkv, kv.page_size, hd), jnp.float32)
+    _compile(paged_attention, one_chip, ((8, cfg.num_heads, hd), jnp.float32),
+             pool, pool, ((8, kv.max_pages_per_seq), jnp.int32),
+             ((8,), jnp.int32))
 
 
 @pytest.mark.parametrize("sq,sk,nq,nkv,hd,causal", [
