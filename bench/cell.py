@@ -15,7 +15,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from bench import correct, counts, model, serving
+from bench import correct, serving
 from bench.instrument import CompileClock, Recorder
 from bench.spec import Cell
 from bench.traffic import Traffic
@@ -34,7 +34,7 @@ def log(msg: str) -> None:
 class Run:
     """Everything a per-layer reader may read (``bench/layer_metrics``)."""
     cell: Cell
-    dims: counts.Dims
+    dims: Any                           # the model kind's dims(cfg)
     peaks: Dict
     system: Any
     recorder: Recorder
@@ -134,9 +134,10 @@ def _warm_programs(system, traffic: Traffic, orch) -> None:
 
 
 class Bench:
-    """The cell's system, built, warmed and serving: weights from the
-    seed, the graph's engines behind the program's threaded
-    ``Orchestrator``, the benchmark's recorders installed."""
+    """The cell's system, built, warmed and serving: weights of the
+    configuration's model kind from the seed, the graph's engines behind
+    the program's threaded ``Orchestrator``, the benchmark's recorders
+    installed."""
 
     def __init__(self, cell: Cell, seed: int, trace: bool, t_start: float,
                  seconds: float):
@@ -146,7 +147,8 @@ class Bench:
         self.cell, self.seed, self.t_start = cell, seed, t_start
         self.seconds = seconds
         cfg = cell.config
-        self.dims = counts.Dims.from_config(cfg)
+        kind = cell.model_module()
+        self.dims = kind.dims(cfg)
         self.clock = clock = CompileClock.install()
         self.split = split = {}
         t = time.perf_counter()
@@ -160,12 +162,12 @@ class Bench:
                 f"cache hits {hits})")
             return now
 
-        self.params = model.init_params(cfg, seed)
+        self.params = kind.init_params(cfg, seed)
         t = phase("weights_s", t)
         self.system = cell.graph_module().build(
-            cfg, model.model_config(cfg), self.params, seed)
+            cfg, kind.model_config(cfg), self.params, seed)
         t = phase("engines_host_embed_s", t)
-        self.recorder = Recorder(self.dims, spans=trace)
+        self.recorder = Recorder(self.dims, kind, spans=trace)
         self.recorder.install(self.system)
         self.orch = Orchestrator(self.system.graph, self.system.engines,
                                  config=ServeConfig(backend="threaded"))

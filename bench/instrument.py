@@ -22,11 +22,10 @@ from __future__ import annotations
 import contextlib
 import time
 from dataclasses import dataclass, field
+from types import ModuleType
 from typing import Any, Dict, List, Tuple
 
 import numpy as np
-
-from bench import counts
 
 
 class CompileClock:
@@ -67,7 +66,8 @@ class CompileClock:
 
 @dataclass
 class Recorder:
-    dims: counts.Dims
+    dims: Any                   # the model kind's dims(cfg)
+    kind: ModuleType            # bench/models/<kind>.py: the step FLOPs
     spans: bool = False
     # stage -> [(t0, t1, model flops of the step)]
     steps: Dict[str, List[Tuple[float, float, int]]] = field(
@@ -113,14 +113,14 @@ class Recorder:
             def on_prefill(t0, t1, a, out, stage=stage):
                 start, valid = int(a[2]), int(a[3])
                 self.prefills[stage].append((t0, t1, start, valid))
-                self._step_flops[stage] += counts.prefill_chunk_flops(
+                self._step_flops[stage] += self.kind.prefill_chunk_flops(
                     self.dims, start, valid)
 
             def on_decode(t0, t1, a, out, stage=stage):
                 pos, act = np.asarray(a[2]), np.asarray(a[3], bool)
                 lens = [int(p) + 1 for p in pos[act]]
                 self.decodes[stage].append((t0, t1, lens))
-                self._step_flops[stage] += counts.decode_step_flops(
+                self._step_flops[stage] += self.kind.decode_step_flops(
                     self.dims, lens)
 
             def on_step(t0, t1, a, events, stage=stage,

@@ -28,7 +28,7 @@ def aot(config: str) -> None:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench import model
+    from bench.spec import model_module
     from repro.engine.kv_cache import PagedKVConfig
     from repro.engine.runner import PagedRunner
     from repro.kernels import ops
@@ -37,7 +37,8 @@ def aot(config: str) -> None:
     jax.config.update("jax_enable_compilation_cache", False)
     cfg = json.loads((ROOT / "bench" / "configs" / f"{config}.json")
                      .read_text())
-    mc = model.model_config(cfg)
+    kind = model_module(cfg)
+    mc = kind.model_config(cfg)
     s = cfg["serving"]
     page = s["page_size"]
     pools = ({"prefill": s["prefill"]["pages"], "decode": s["decode"]["pages"]}
@@ -55,15 +56,14 @@ def aot(config: str) -> None:
     ops.paged_attention = lambda q, k, v, bt, sl, **kw: pa.paged_attention(
         q, k, v, bt, sl, interpret=False, **kw)
     params = jax.tree.map(lambda shp: sds(shp, cfg["torch_dtype"]),
-                          model.param_shapes(cfg),
+                          kind.param_shapes(cfg),
                           is_leaf=lambda x: isinstance(x, tuple))
     for stage, pages in pools.items():
         kv = PagedKVConfig(num_pages=pages, page_size=page,
                            max_pages_per_seq=s["max_seq"] // page)
         r = object.__new__(PagedRunner)
         r.cfg, r.kv, r.quant = mc, kv, False
-        pool = sds((mc.num_layers, pages, mc.num_kv_heads, page,
-                    mc.head_dim), cfg["torch_dtype"])
+        pool = sds(kind.kv_pool_shape(cfg, pages), cfg["torch_dtype"])
         pp = kv.max_pages_per_seq
         B = batch[stage]
         progs = {

@@ -1,16 +1,13 @@
 """Smoke-width copies of a cell, for the CPU: the same graph, traffic
-shape and code path at a size the CPU can serve in seconds.  Used by
-``bench/tests`` and ``bench/rehearse.py``; never by a measured run."""
+shape and code path at a size the CPU can serve in seconds.  The model's
+widths are its kind's ``smoke_widths``; serving and traffic shrink here
+alike for every kind.  Used by ``bench/tests``; never by a measured run."""
 from __future__ import annotations
 
 import copy
 from typing import Dict
 
 from bench.spec import Cell
-
-SMOKE_WIDTHS = {"hidden_size": 256, "intermediate_size": 512,
-                "num_hidden_layers": 2, "num_attention_heads": 8,
-                "head_dim": 32, "vocab_size": 1024}
 
 
 def _shrink_len(d: Dict, lo: int, hi: int) -> Dict:
@@ -20,9 +17,7 @@ def _shrink_len(d: Dict, lo: int, hi: int) -> Dict:
 def smoke_cell(cell: Cell, **traffic_overrides) -> Cell:
     c = copy.deepcopy(cell)
     cfg = c.config
-    kv_ratio = cfg["num_attention_heads"] // cfg["num_key_value_heads"]
-    cfg.update(SMOKE_WIDTHS)
-    cfg["num_key_value_heads"] = SMOKE_WIDTHS["num_attention_heads"] // kv_ratio
+    cfg.update(c.model_module().smoke_widths(cfg))
     s = cfg["serving"]
     s.update(max_seq=256, chunk=32)
     t = c.traffic

@@ -1,6 +1,9 @@
 """Resolve a cell named in ``BENCHMARK.json`` to its files, by name only.
 
 - configuration ``<c>``: the ``file`` its entry names (``bench/configs/``);
+- model kind: ``bench/models/<model>.py``, ``model`` read from the
+  configuration file (``dense`` where it names none): the program's
+  ``ModelConfig``, the weights, the FLOP counts and the smoke widths;
 - traffic mix ``<t>``: ``bench/traffic/<t>.json``;
 - serving graph: ``bench/graphs/<graph>.py``, ``graph`` read from the
   configuration file;
@@ -8,8 +11,8 @@
 - per-layer metric ``<m>``: ``bench/layer_metrics/<m>.py``, whose
   ``read(ctx)`` returns the number or ``None`` when it finds nothing.
 
-A later change adds a configuration, a mix, a graph or a metric by adding
-its file and its entry; nothing here names one.
+A later change adds a configuration, a model kind, a mix, a graph or a
+metric by adding its file and its entry; nothing here names one.
 """
 from __future__ import annotations
 
@@ -23,6 +26,8 @@ from typing import Dict, List
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+#: the kind of a configuration file that names none
+DEFAULT_MODEL = "dense"
 
 
 class SpecError(ValueError):
@@ -55,6 +60,9 @@ class Cell:
     per_layer: List[Dict]     # metric entries this cell reports, trace 1
     bench_dir: Path = BENCH_DIR
 
+    def model_module(self) -> ModuleType:
+        return model_module(self.config, self.bench_dir)
+
     def graph_module(self) -> ModuleType:
         return load_module(self.bench_dir / "graphs"
                            / f"{self.config['graph']}.py")
@@ -65,6 +73,16 @@ class Cell:
 
     def metric_reader(self, name: str) -> ModuleType:
         return load_module(self.bench_dir / "layer_metrics" / f"{name}.py")
+
+
+def model_module(config: Dict, bench_dir: Path = BENCH_DIR) -> ModuleType:
+    """The configuration's model kind, ``bench/models/<model>.py``, which
+    exports ``model_config(cfg)``, ``param_shapes(cfg)``,
+    ``kv_pool_shape(cfg, pages)``, ``init_params(cfg, seed)``,
+    ``dims(cfg)``, ``prefill_chunk_flops(dims, start, valid)``,
+    ``decode_step_flops(dims, seq_lens)`` and ``smoke_widths(cfg)``."""
+    kind = config.get("model", DEFAULT_MODEL)
+    return load_module(bench_dir / "models" / f"{kind}.py")
 
 
 def _applies(metric: Dict, cell: str) -> bool:
