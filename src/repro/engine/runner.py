@@ -6,6 +6,11 @@ PagedRunner (dense / moe / vlm stages):
     (chunked prefill, Sarathi-style).
   - ``decode``: batched one-token step for ALL active slots against the
     shared page pool (vLLM-style paged attention).
+  Both scan the layers with the whole pools, K and V (L, P, nkv, page, hd)
+  and the int8 scales (L, P, nkv, page), in the scan's carry: a layer
+  scatters its new tokens into them at its index, and attention reads
+  them by that index, so no layer's pool is sliced out or restacked and
+  the donated pools are updated in place.
 
 StateRunner (ssm / hybrid stages): constant-size recurrent state per slot
 (+ dense KV for the hybrid's shared-attention sites), reusing the
@@ -38,6 +43,25 @@ def _mlp_or_moe(cfg, lp, h):
     return L.mlp(lp["mlp"], h)
 
 
+def _write_kv(pools, layer, pid, slot, k, v, quant: bool):
+    """Scatter new K/V (T, nkv, hd) into the whole pools at ``layer``,
+    token t at page ``pid[t]``, slot ``slot[t]``; an out-of-range page id
+    drops its token.  pools: (k, v, k_scales, v_scales), scales None
+    unless ``quant``."""
+    heads = jnp.arange(k.shape[1])[None, :]
+
+    def put(pool, x):
+        return pool.at[layer, pid[:, None], heads, slot[:, None]].set(
+            x.astype(pool.dtype), mode="drop")
+
+    kp, vp, ksp, vsp = pools
+    if quant:
+        kq, ks = L.quantize_kv(k)
+        vq, vs = L.quantize_kv(v)
+        return put(kp, kq), put(vp, vq), put(ksp, ks), put(vsp, vs)
+    return put(kp, k), put(vp, v), ksp, vsp
+
+
 class PagedRunner:
     """Paged-KV execution for attention architectures."""
 
@@ -54,10 +78,10 @@ class PagedRunner:
                 cfg, kv, cfg.num_layers)
         else:
             self.k_scales = self.v_scales = None
-        self._prefill_jit = jax.jit(
-            self._prefill_impl, donate_argnums=(1, 2),
-            static_argnames=())
-        self._decode_jit = jax.jit(self._decode_impl, donate_argnums=(1, 2))
+        self._prefill_jit = jax.jit(self._prefill_impl,
+                                    donate_argnums=(1, 2, 3, 4))
+        self._decode_jit = jax.jit(self._decode_impl,
+                                   donate_argnums=(1, 2, 3, 4))
         # how much of the paged kernel's grid does work (host arithmetic on
         # the decode batch, whatever the backend): ``blocks_in_grid`` counts
         # its B x ceil(pp / ppb) steps per call, ``blocks_live`` those that
@@ -92,37 +116,29 @@ class PagedRunner:
                         self.kv.num_pages)                    # OOB => dropped
         slot = pos_flat % page
 
-        def body(h, xs):
-            lp, kp, vp, ksp, vsp = xs
+        def body(carry, xs):
+            h, pools = carry
+            lp, layer = xs
             hn = L.rmsnorm(lp["ln1"], h, cfg.rmsnorm_eps)
             q, k, v = L._qkv(cfg, lp["attn"], hn)
             if cfg.rope_theta:
                 q = L.rope(q, positions, cfg.rope_theta)
                 k = L.rope(k, positions, cfg.rope_theta)
-            if self.quant:
-                kq, ks = L.quantize_kv(k)
-                vq, vs = L.quantize_kv(v)
-                kp = kp.at[pid, :, slot].set(kq[0], mode="drop")
-                vp = vp.at[pid, :, slot].set(vq[0], mode="drop")
-                ksp = ksp.at[pid, :, slot].set(ks[0], mode="drop")
-                vsp = vsp.at[pid, :, slot].set(vs[0], mode="drop")
-            else:
-                kp = kp.at[pid, :, slot].set(k[0].astype(kp.dtype),
-                                             mode="drop")
-                vp = vp.at[pid, :, slot].set(v[0].astype(vp.dtype),
-                                             mode="drop")
-            k_all = ref.gather_pages(kp, block_table, ksp).astype(h.dtype)
-            v_all = ref.gather_pages(vp, block_table, vsp).astype(h.dtype)
-            o = ref.chunk_attention(q, k_all[None], v_all[None], start,
+            pools = _write_kv(pools, layer, pid, slot, k[0], v[0], self.quant)
+            kp, vp, ksp, vsp = pools
+            k_all = ref.gather_pages(kp, block_table, ksp, layer=layer)
+            v_all = ref.gather_pages(vp, block_table, vsp, layer=layer)
+            o = ref.chunk_attention(q, k_all[None].astype(h.dtype),
+                                    v_all[None].astype(h.dtype), start,
                                     window=window)
             h = h + jnp.einsum("bsqh,qhd->bsd", o, lp["attn"]["wo"])
             hn = L.rmsnorm(lp["ln2"], h, cfg.rmsnorm_eps)
             h = h + _mlp_or_moe(cfg, lp, hn)
-            return h, (kp, vp, ksp, vsp)
+            return (h, pools), None
 
-        scales = ((k_scales, v_scales) if self.quant else (None, None))
-        h, (k_pages, v_pages, k_scales, v_scales) = jax.lax.scan(
-            body, embeds, (params["blocks"], k_pages, v_pages, *scales))
+        (h, (k_pages, v_pages, k_scales, v_scales)), _ = jax.lax.scan(
+            body, (embeds, (k_pages, v_pages, k_scales, v_scales)),
+            (params["blocks"], jnp.arange(k_pages.shape[0])))
         hidden = h[0]
         logits = T._unembed(cfg, params, h)[0]
         return logits, hidden, k_pages, v_pages, k_scales, v_scales
@@ -224,37 +240,29 @@ class PagedRunner:
         slot = positions % page
         seq_lens = jnp.where(active, positions + 1, 0)
 
-        def body(h, xs):
-            lp, kp, vp, ksp, vsp = xs
+        def body(carry, xs):
+            h, pools = carry
+            lp, layer = xs
             hn = L.rmsnorm(lp["ln1"], h, cfg.rmsnorm_eps)
             q, k, v = L._qkv(cfg, lp["attn"], hn)
             if cfg.rope_theta:
                 q = L.rope(q, positions[:, None], cfg.rope_theta)
                 k = L.rope(k, positions[:, None], cfg.rope_theta)
-            if self.quant:
-                kq, ks = L.quantize_kv(k)
-                vq, vs = L.quantize_kv(v)
-                kp = kp.at[pid, :, slot].set(kq[:, 0], mode="drop")
-                vp = vp.at[pid, :, slot].set(vq[:, 0], mode="drop")
-                ksp = ksp.at[pid, :, slot].set(ks[:, 0], mode="drop")
-                vsp = vsp.at[pid, :, slot].set(vs[:, 0], mode="drop")
-            else:
-                kp = kp.at[pid, :, slot].set(k[:, 0].astype(kp.dtype),
-                                             mode="drop")
-                vp = vp.at[pid, :, slot].set(v[:, 0].astype(vp.dtype),
-                                             mode="drop")
+            pools = _write_kv(pools, layer, pid, slot, k[:, 0], v[:, 0],
+                              self.quant)
+            kp, vp, ksp, vsp = pools
             o = ops.paged_attention(q[:, 0], kp, vp, block_tables, seq_lens,
                                     window=window, k_scale_pages=ksp,
-                                    v_scale_pages=vsp)
+                                    v_scale_pages=vsp, layer=layer)
             h = h + jnp.einsum("bqh,qhd->bd", o.astype(h.dtype),
                                lp["attn"]["wo"])[:, None]
             hn = L.rmsnorm(lp["ln2"], h, cfg.rmsnorm_eps)
             h = h + _mlp_or_moe(cfg, lp, hn)
-            return h, (kp, vp, ksp, vsp)
+            return (h, pools), None
 
-        scales = ((k_scales, v_scales) if self.quant else (None, None))
-        h, (k_pages, v_pages, k_scales, v_scales) = jax.lax.scan(
-            body, embeds, (params["blocks"], k_pages, v_pages, *scales))
+        (h, (k_pages, v_pages, k_scales, v_scales)), _ = jax.lax.scan(
+            body, (embeds, (k_pages, v_pages, k_scales, v_scales)),
+            (params["blocks"], jnp.arange(k_pages.shape[0])))
         hidden = h[:, 0]
         logits = T._unembed(cfg, params, h)[:, 0]
         return logits, hidden, k_pages, v_pages, k_scales, v_scales

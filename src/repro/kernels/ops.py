@@ -74,17 +74,17 @@ def decode_attention(q, k_cache, v_cache, pos, *, window=0, backend=None,
 
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
                     window=0, backend=None, k_scale_pages=None,
-                    v_scale_pages=None):
+                    v_scale_pages=None, layer=None):
     if get_backend(backend) == "pallas":
         from repro.kernels import paged_attention as pa
         return pa.paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
                                   window=window,
                                   k_scale_pages=k_scale_pages,
-                                  v_scale_pages=v_scale_pages,
+                                  v_scale_pages=v_scale_pages, layer=layer,
                                   interpret=jax.default_backend() != "tpu")
     return ref.paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
                                window=window, k_scale_pages=k_scale_pages,
-                               v_scale_pages=v_scale_pages)
+                               v_scale_pages=v_scale_pages, layer=layer)
 
 
 def mamba1_scan(x, dt, A, B, C, D, h0=None, *, backend=None):

@@ -22,9 +22,12 @@ TPU-native design notes:
     about ``_BLOCK_TOKENS`` tokens whose double buffer fits
     ``_BUFFER_BYTES`` of VMEM. When it does not divide ``pp``, the last
     block's missing slots are masked like any slot past ``seq_len``.
-  - The pool is head-major, (P, nkv, page, hd): one copy moves a whole
-    page for every kv head, and each head's (page, hd) slab is a full
-    (sublane, lane) tile.
+  - The pool is an engine's whole pool, (L, P, nkv, page, hd), head-major:
+    one copy moves a whole page of one layer for every kv head, and each
+    head's (page, hd) slab is a full (sublane, lane) tile.  The layer is
+    a third scalar-prefetch operand, so a layer scan hands the kernel the
+    pool it carries, neither sliced nor copied.  (A 4-D pool is a
+    one-layer pool.)
   - GQA: the g query heads of one kv head are the rows of a (g, hd) MXU
     tile; kv heads are a static loop inside the step.
   - int8 pools keep HBM traffic at 1 B/elem: the per-token scales are
@@ -75,12 +78,13 @@ def live_block_count(seq_lens: np.ndarray, block: int,
     return int(np.where(seq_lens > 0, last - first + 1, 0).sum())
 
 
-def _pa_kernel(bt_ref, sl_ref,                      # scalar prefetch
+def _pa_kernel(bt_ref, sl_ref, layer_ref,           # scalar prefetch
                q_ref, k_hbm, v_hbm, *refs, page: int, pp: int, ppb: int,
                window: int, quant: bool):
     if quant:
         ks_ref, vs_ref, *refs = refs
     o_ref, k_buf, v_buf, sems, state_ref, m_ref, l_ref, acc_ref = refs
+    layer = layer_ref[0]
     b, i = pl.program_id(0), pl.program_id(1)
     nb, nblk = pl.num_programs(0), pl.num_programs(1)
     nkv, g, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
@@ -105,7 +109,8 @@ def _pa_kernel(bt_ref, sl_ref,                      # scalar prefetch
             yield s, j, ok
 
     def page_copies(page_id, slot, j):
-        return [pltpu.make_async_copy(src.at[page_id], dst.at[slot, j],
+        return [pltpu.make_async_copy(src.at[layer, page_id],
+                                      dst.at[slot, j],
                                       sems.at[slot])
                 for src, dst in ((k_hbm, k_buf), (v_hbm, v_buf))]
 
@@ -211,14 +216,14 @@ def _pa_kernel(bt_ref, sl_ref,                      # scalar prefetch
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _block_scales(k_scale_pages, v_scale_pages, block_tables, seq_lens,
-                  ppb: int, nblk: int, window: int):
-    """The int8 scales of every block's tokens, (B, nblk, nkv, ppb * page)
-    each for K and V: one lane row per kv head, as the scores lie. Slots
-    that hold no context take page 0's scales, so no page past a
-    sequence's context is read."""
+def _block_scales(k_scale_pages, v_scale_pages, layer, block_tables,
+                  seq_lens, ppb: int, nblk: int, window: int):
+    """The int8 scales of ``layer``'s tokens in every block, (B, nblk, nkv,
+    ppb * page) each for K and V: one lane row per kv head, as the scores
+    lie. Slots that hold no context take page 0's scales, so no page past
+    a sequence's context is read."""
     b, pp = block_tables.shape
-    _, nkv, page = k_scale_pages.shape
+    _, _, nkv, page = k_scale_pages.shape
     slot = jnp.arange(nblk * ppb)[None, :]
     live = slot * page < seq_lens[:, None]
     if window > 0:
@@ -227,7 +232,8 @@ def _block_scales(k_scale_pages, v_scale_pages, block_tables, seq_lens,
     bt = jnp.where(live, bt, 0)
 
     def rows(scale_pages):
-        x = scale_pages[bt].astype(jnp.float32)     # (B, nblk*ppb, nkv, page)
+        # (B, nblk * ppb, nkv, page) -> (B, nblk, nkv, ppb * page)
+        x = scale_pages[layer, bt].astype(jnp.float32)
         x = x.reshape(b, nblk, ppb, nkv, page).transpose(0, 1, 3, 2, 4)
         return x.reshape(b, nblk, nkv, ppb * page)
 
@@ -239,42 +245,56 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     block_tables: jax.Array, seq_lens: jax.Array, *,
                     k_scale_pages: jax.Array | None = None,
                     v_scale_pages: jax.Array | None = None,
+                    layer: jax.Array | int | None = None,
                     window: int = 0, interpret: bool = False) -> jax.Array:
-    """q: (B, nq, hd); k/v_pages: (P, nkv, page, hd);
-    block_tables: (B, pages_per_seq) int32; seq_lens: (B,) int32, at most
-    ``pages_per_seq * page``. Optional k/v_scale_pages: (P, nkv, page) f32
-    — int8-quantized pool with in-kernel dequantization.
+    """q: (B, nq, hd); k/v_pages: (L, P, nkv, page, hd), read at ``layer``,
+    or (P, nkv, page, hd) with ``layer`` None; block_tables: (B,
+    pages_per_seq) int32; seq_lens: (B,) int32, at most ``pages_per_seq *
+    page``. Optional k/v_scale_pages: (L, P, nkv, page), or (P, nkv, page),
+    f32 — int8-quantized pool with in-kernel dequantization.
     Returns (B, nq, hd); a sequence with ``seq_len == 0`` gives zeros."""
+    quant = k_scale_pages is not None
+    if layer is None:                      # a one-layer pool
+        k_pages, v_pages, k_scale_pages, v_scale_pages = (
+            None if x is None else x[None]
+            for x in (k_pages, v_pages, k_scale_pages, v_scale_pages))
+        layer = 0
+    layer = jnp.asarray(layer, jnp.int32)
     b, nq, hd = q.shape
-    _, nkv, page, _ = k_pages.shape
+    _, _, nkv, page, _ = k_pages.shape
     pp = block_tables.shape[1]
     g = nq // nkv
     scale = hd ** -0.5
-    quant = k_scale_pages is not None
 
     # (B, nkv, g, hd) so each kv head's query group is one tile; scaled in
     # f32, as the reference does (a bf16 product would round q)
     qg = (q.astype(jnp.float32) * scale).reshape(b, nkv, g, hd)
+    pool_layer = layer
     if hd % 128:
         # a page copy must move whole 128-lane rows: a narrower head (the
         # tiny test widths) is zero-padded, which leaves every score and
-        # output lane below hd as it was, at one pool copy a call
-        pad = ((0, 0),) * 3 + ((0, -hd % 128),)
-        qg, k_pages, v_pages = (jnp.pad(x, pad)
-                                for x in (qg, k_pages, v_pages))
+        # output lane below hd as it was.  Only the layer read is padded,
+        # at one layer's pool a call
+        pad = ((0, 0),) * 4 + ((0, -hd % 128),)
+        k_pages, v_pages = (
+            jnp.pad(jax.lax.dynamic_slice_in_dim(x, layer, 1), pad)
+            for x in (k_pages, v_pages))
+        qg = jnp.pad(qg, pad[1:])
+        pool_layer = jnp.zeros((), jnp.int32)
     hd_lanes = qg.shape[-1]
     ppb = pages_per_block(page, pp, nkv, hd, k_pages.dtype.itemsize, quant)
     nblk = pl.cdiv(pp, ppb)
     head_block = pl.BlockSpec((1, nkv, g, hd_lanes),
-                              lambda b_, i, bt, sl: (b_, 0, 0, 0))
+                              lambda b_, i, bt, sl, ly: (b_, 0, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
 
     in_specs = [head_block, hbm, hbm]
-    operands = [block_tables.reshape(-1), seq_lens, qg, k_pages, v_pages]
+    operands = [block_tables.reshape(-1), seq_lens, pool_layer.reshape(1),
+                qg, k_pages, v_pages]
     if quant:
         bk = ppb * page
 
-        def scale_block(b_, i, bt, sl):
+        def scale_block(b_, i, bt, sl, ly):
             # steps outside the context keep a working block's index, so
             # the pipeline fetches nothing for them
             last = jnp.maximum(sl[b_] - 1, 0) // bk
@@ -282,8 +302,8 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             return b_, jnp.minimum(jnp.maximum(i, first), last), 0, 0
 
         in_specs += [pl.BlockSpec((1, 1, nkv, bk), scale_block)] * 2
-        operands += _block_scales(k_scale_pages, v_scale_pages, block_tables,
-                                  seq_lens, ppb, nblk, window)
+        operands += _block_scales(k_scale_pages, v_scale_pages, layer,
+                                  block_tables, seq_lens, ppb, nblk, window)
     scratch = [
         pltpu.VMEM((2, ppb, nkv, page, hd_lanes), k_pages.dtype),
         pltpu.VMEM((2, ppb, nkv, page, hd_lanes), v_pages.dtype),
@@ -295,7 +315,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, nblk),
         in_specs=in_specs,
         out_specs=head_block,

@@ -97,20 +97,24 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     block_tables: jax.Array, seq_lens: jax.Array, *,
                     window: int = 0, scale: float | None = None,
                     k_scale_pages: jax.Array | None = None,
-                    v_scale_pages: jax.Array | None = None) -> jax.Array:
+                    v_scale_pages: jax.Array | None = None,
+                    layer: jax.Array | int | None = None) -> jax.Array:
     """Decode attention over a block-paged KV cache (vLLM PagedAttention).
 
     q: (B, nq, hd) — one query token per sequence.
-    k_pages/v_pages: (num_pages, nkv, page_size, hd) — the global page pool,
-    head-major so each head's page is one (page, hd) tile.
+    k_pages/v_pages: (num_layers, num_pages, nkv, page_size, hd) — every
+    layer's global page pool, read at ``layer`` — or, with ``layer`` None,
+    one layer's (num_pages, nkv, page_size, hd); head-major so each head's
+    page is one (page, hd) tile.
     block_tables: (B, pages_per_seq) int32 page ids (padded arbitrarily).
     seq_lens: (B,) int32 — number of valid tokens (incl. current).
-    k/v_scale_pages: optional (num_pages, nkv, page_size) dequant scales for
-    int8-quantized page pools.
+    k/v_scale_pages: optional dequant scales for int8-quantized page pools,
+    the pools' shape without ``hd``.
     """
     b, nq, hd = q.shape
-    k = gather_pages(k_pages, block_tables, k_scale_pages)  # (B, T, nkv, hd)
-    v = gather_pages(v_pages, block_tables, v_scale_pages)
+    k = gather_pages(k_pages, block_tables, k_scale_pages,
+                     layer=layer)                     # (B, T, nkv, hd)
+    v = gather_pages(v_pages, block_tables, v_scale_pages, layer=layer)
     nkv, t = k.shape[2], k.shape[1]
     scale = scale if scale is not None else hd ** -0.5
     qg = q.reshape(b, 1, nkv, nq // nkv, hd).astype(jnp.float32)
@@ -126,16 +130,19 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 
 
 def gather_pages(pages: jax.Array, block_tables: jax.Array,
-                 scale_pages: jax.Array | None = None) -> jax.Array:
+                 scale_pages: jax.Array | None = None, *,
+                 layer: jax.Array | int | None = None) -> jax.Array:
     """Token-major f32 K or V of each sequence from a head-major page pool.
 
-    pages: (P, nkv, page, hd); block_tables: (..., pp); scale_pages:
-    optional (P, nkv, page) int8 dequant scales.
-    Returns (..., pp * page, nkv, hd) float32.
+    pages: (L, P, nkv, page, hd), gathered at ``layer`` in one gather, or
+    (P, nkv, page, hd) with ``layer`` None; block_tables: (..., pp);
+    scale_pages: optional int8 dequant scales, ``pages``' shape without
+    ``hd``.  Returns (..., pp * page, nkv, hd) float32.
     """
-    x = pages[block_tables].astype(jnp.float32)   # (..., pp, nkv, page, hd)
+    idx = block_tables if layer is None else (layer, block_tables)
+    x = pages[idx].astype(jnp.float32)            # (..., pp, nkv, page, hd)
     if scale_pages is not None:
-        x = x * scale_pages[block_tables].astype(jnp.float32)[..., None]
+        x = x * scale_pages[idx].astype(jnp.float32)[..., None]
     x = jnp.swapaxes(x, -3, -2)                   # (..., pp, page, nkv, hd)
     return x.reshape(*x.shape[:-4], -1, *x.shape[-2:])
 

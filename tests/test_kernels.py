@@ -122,6 +122,26 @@ def test_paged_attention_blocks(case, seq_lens, window, dtype, poison):
     page that holds no context points at a page of NaN (NaN scales for
     int8): the output stays equal to the oracle's on a clean table, so no
     such page is read."""
+    _check_blocks(seq_lens, window, dtype, poison)
+
+
+@pytest.mark.parametrize("layers,layer,seq_lens,window,dtype", [
+    (3, 0, (600, 257, 1), 0, jnp.float32),
+    (3, 1, (600, 300, 40), 100, jnp.int8),
+    (3, 2, (600, 300, 40), 100, jnp.bfloat16),
+    (3, 2, (0, 257, 512), 100, jnp.int8),
+])
+def test_paged_attention_reads_layer_of_whole_pool(layers, layer, seq_lens,
+                                                    window, dtype):
+    """A layer scan hands the kernel every layer's pool, (L, P, nkv, page,
+    hd), and the layer to read: the output equals the oracle's on that
+    layer, at the first, a middle and the last layer, with int8 scales and
+    a sliding window.  Every other layer holds NaN (NaN scales for int8),
+    so no page of another layer is read."""
+    _check_blocks(seq_lens, window, dtype, True, layers=layers, layer=layer)
+
+
+def _check_blocks(seq_lens, window, dtype, poison, layers=None, layer=None):
     b, nq, nkv, hd, page, pp = (_BLK[k] for k in
                                 ("b", "nq", "nkv", "hd", "page", "pp"))
     quant = dtype == jnp.int8
@@ -137,9 +157,18 @@ def test_paged_attention_blocks(case, seq_lens, window, dtype, poison):
         s = jnp.max(jnp.abs(kv), axis=-1) / 127.0 + 1e-8
         pools = jnp.round(kv / s[..., None]).astype(jnp.int8)
         s = s.at[:, poison_page].set(jnp.nan)
-        scales = dict(k_scale_pages=s[0], v_scale_pages=s[1])
     else:
         pools = kv.astype(dtype).at[:, poison_page].set(jnp.nan)
+    if layers is not None:
+        # the pools of the other layers: NaN, or random int8 at NaN scales
+        def whole(x, fill):
+            return jnp.full((2, layers) + x.shape[1:], fill,
+                            x.dtype).at[:, layer].set(x)
+        pools = whole(pools, 1 if quant else jnp.nan)
+        if quant:
+            s = whole(s, jnp.nan)
+    if quant:
+        scales = dict(k_scale_pages=s[0], v_scale_pages=s[1])
     bt = jax.random.permutation(KEYS[6], P - 1).reshape(b, pp)
     bt = bt.astype(jnp.int32)
     sl = jnp.asarray(seq_lens, jnp.int32)
@@ -149,9 +178,9 @@ def test_paged_attention_blocks(case, seq_lens, window, dtype, poison):
         held &= (slot + 1) * page > sl[:, None] - window
     table = jnp.where(held, bt, poison_page) if poison else bt
     got = paged_attention(q, pools[0], pools[1], table, sl, window=window,
-                          interpret=True, **scales)
+                          layer=layer, interpret=True, **scales)
     want = ref.paged_attention(q, pools[0], pools[1], bt, sl, window=window,
-                               **scales)
+                               layer=layer, **scales)
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     assert np.isfinite(got).all()
     live = np.asarray(sl) > 0

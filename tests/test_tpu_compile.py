@@ -3,7 +3,8 @@
 The TPU compiler is installed without a chip: each test lowers a kernel
 at real widths for a described v5e device and compiles it, which catches
 what interpret mode cannot (block shapes the tiling refuses, slices not
-provably aligned, too much VMEM).  Nothing runs.
+provably aligned, too much VMEM), or a runner program, whose memory the
+compiler reckons.  Nothing runs.
 
 This is the only test file that describes the chip.  The topology is
 built inside a module fixture, never at import, so every xdist worker
@@ -18,9 +19,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import get_config
 from repro.configs.pipelines import _kv, tiny_lm
+from repro.engine.kv_cache import PagedKVConfig
+from repro.engine.runner import PagedRunner
+from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mamba_scan import mamba1_scan
 from repro.kernels.paged_attention import paged_attention
+from repro.models import transformer as T
 
 
 @pytest.fixture(scope="module")
@@ -106,6 +111,60 @@ def test_paged_decode_compiles_at_tiny_widths(one_chip):
     _compile(paged_attention, one_chip, ((8, cfg.num_heads, hd), jnp.float32),
              pool, pool, ((8, kv.max_pages_per_seq), jnp.int32),
              ((8,), jnp.int32))
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_runner_updates_pools_in_place(one_chip, monkeypatch, program,
+                                       kv_dtype):
+    """The runner's prefill and decode programs at the qwen1_5_4b cell's
+    widths (40 layers, 560 pages of 16, 16 slots, chunk 64), the pools
+    donated: the layer scan carries them whole and writes them in place,
+    so the program holds no second pool.  Its temporaries stay under a
+    tenth of the pools' bytes (K and V, and the int8 scales), and the
+    outputs alias every pool byte."""
+    cfg = get_config("qwen1_5_4b").replace(kv_cache_dtype=(
+        "int8" if kv_dtype == "int8" else "bfloat16"))
+    kv = PagedKVConfig(num_pages=560, page_size=16, max_pages_per_seq=128)
+    r = object.__new__(PagedRunner)
+    r.cfg, r.kv, r.quant = cfg, kv, kv_dtype == "int8"
+    # the kernel as the chip runs it (the CPU would pick interpret mode)
+    monkeypatch.setattr(ops, "paged_attention",
+                        lambda q, k, v, bt, sl, **kw: paged_attention(
+                            q, k, v, bt, sl, interpret=False, **kw))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
+    shape = (cfg.num_layers, kv.num_pages, cfg.num_kv_heads, kv.page_size,
+             cfg.head_dim)
+    pool = sds(shape, kv_dtype)
+    scales = sds(shape[:-1], jnp.float32) if r.quant else None
+    pool_bytes = 2 * pool.size * pool.dtype.itemsize
+    if r.quant:
+        pool_bytes += 2 * scales.size * 4
+    b, pp = 16, kv.max_pages_per_seq
+    if program == "decode":
+        fn, rest = r._decode_impl, [sds((b, 1, cfg.d_model), cfg.dtype),
+                                    sds((b, pp), jnp.int32),
+                                    sds((b,), jnp.int32), sds((b,), bool)]
+    else:
+        fn, rest = r._prefill_impl, [sds((1, 64, cfg.d_model), jnp.float32),
+                                     sds((pp,), jnp.int32),
+                                     sds((), jnp.int32), sds((), jnp.int32)]
+    c = jax.jit(fn, donate_argnums=(1, 2, 3, 4)).lower(
+        params, pool, pool, scales, scales, *rest).compile()
+    if program == "decode":
+        assert "tpu_custom_call" in c.as_text()
+    ma = c.memory_analysis()
+    assert ma.temp_size_in_bytes < 0.1 * pool_bytes, (
+        ma.temp_size_in_bytes, pool_bytes)
+    assert ma.alias_size_in_bytes >= pool_bytes, (
+        ma.alias_size_in_bytes, pool_bytes)
 
 
 @pytest.mark.parametrize("sq,sk,nq,nkv,hd,causal", [
